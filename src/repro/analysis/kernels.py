@@ -92,8 +92,9 @@ def _blocks(eqn):
     gm = eqn.params["grid_mapping"]
     out = []
     for bm in gm.block_mappings:
-        sds = bm.array_shape_dtype
-        block = tuple(d for d in bm.block_shape if isinstance(d, int))
+        sds = bm.array_aval
+        dims = (getattr(d, "block_size", d) for d in bm.block_shape)
+        block = tuple(d for d in dims if isinstance(d, int))
         out.append((block, tuple(sds.shape), sds.dtype.itemsize))
     return out
 
@@ -207,11 +208,13 @@ def _repo_cases() -> List[Case]:
     page_tiles = (((pool_d), 1), ((pool_s), 1))
     return [
         Case("fused_matmul_w4a16", mk_fused_w4),
+        # int8 codes reach the kernel as (2, K/2, N) even/odd planes: one
+        # row across the plane axis is one pair (2 values)
         Case("fused_matmul_w8a16", mk_fused_w8,
-             pair_blocks=(((256, 128), 0, 1),)),
+             pair_blocks=(((2, 128, 128), 1, 2),)),
         Case("grouped_matmul_w4a16", mk_grouped_w4),
         Case("grouped_matmul_w8a16", mk_grouped_w8,
-             pair_blocks=(((2, 256, 128), 1, 1),)),
+             pair_blocks=(((2, 2, 128, 128), 2, 2),)),
         Case("ovp_encode", mk_encode),
         Case("decode_attn_slab_packed", mk_decode_slab),
         Case("decode_attn_paged_packed", mk_decode_paged,

@@ -11,14 +11,15 @@ This kernel reads the packed `k_data`/`v_data` nibbles and the
 per-(token, head) scales straight from HBM and unpacks/dequantizes PER KV
 TILE in VMEM, inside the same kernel that consumes them:
 
-  grid      — (batch, kv_head, S/bs) with the kv-tile dim innermost, so
-              the (b, h) output block stays resident in VMEM while tiles
-              stream through; one `pallas_call` per layer per step.
+  grid      — (batch, S/bs) with the kv-tile dim innermost, so the
+              row's (Hkv, G, D) output block stays resident in VMEM while
+              tiles of all its kv heads stream through; one `pallas_call`
+              per layer per step.
   prologue  — a packed tile decodes branch-free on the VPU (same
               nibble-plane trick as `ovp_matmul`: even K-lanes in the high
               nibbles, odd in the low, so no interleaving relayout is ever
               needed); fp16/bf16 caches take the same kernel minus the
-              unpack phase (the planes are strided slices of the fp tile).
+              unpack phase, in natural lane order.
   body      — online-softmax accumulation in f32: scores fold the
               per-token K scale in (s = (q @ k_codes^T) * k_scl), the
               probabilities fold the V scale (p * v_scl) so decoded code
@@ -34,8 +35,9 @@ HBM read per decode step for the packed path drops ~4x vs the dequant
 path (1 byte per 2 values + one f32 scale per (token, head) vs 2-4 bytes
 per value), and the full-cache dequant materialization disappears.
 
-Outputs keep the even/odd plane layout (first D/2 lanes = even K-lanes);
-the public wrapper re-interleaves once on the (B, 1, H, D) result.
+For packed caches the queries enter, and the outputs leave, in the
+even/odd plane layout (first D/2 lanes = even K-lanes); the public wrapper
+permutes once on the (B, 1, H, D) operands.
 
 PAGED CACHES (serve/paging.py): a paged cache stores its K/V data as a
 global `(n_pages, page_size, Hkv, …)` pool plus a per-row block table
@@ -234,40 +236,66 @@ def _decline_reason(q: jax.Array, cache) -> Optional[str]:
 
 
 # --------------------------------------------------------------------------
-# Kernel bodies. Blocks carry `bh` kv heads (default 1 — one head per grid
-# step, the TPU-parallel layout; interpret mode folds all heads into one
-# tile to amortize the per-grid-step interpreter overhead — numerics are
-# identical, it is a block-size tunable exactly like bm/bn/bk in the
-# matmul kernel).
+# Kernel bodies. A block carries every kv head of its rows: a TPU block's
+# last two dims must be (8, 128)-aligned or whole, and the cache's trailing
+# (Hkv, D) dims are whole only together. In VMEM a tile is turned
+# head-major, (bs, Hkv, D) -> (Hkv, bs, D), so both attention products are
+# head-batched matmuls with the batch dim leading, as Mosaic wants.
 # --------------------------------------------------------------------------
-_BATCHED = (((2,), (2,)), ((0,), (1,)))   # (bh,G,x) @ (bs,bh,x) -> (bh,G,bs)
-_BATCHED_PV = (((2,), (0,)), ((0,), (1,)))  # (bh,G,bs) @ (bs,bh,x)
+_QK = (((2,), (2,)), ((0,), (0,)))   # (Hkv,R,D) x (Hkv,bs,D) -> (Hkv,R,bs)
+_PV = (((2,), (1,)), ((0,), (0,)))   # (Hkv,R,bs) x (Hkv,bs,D) -> (Hkv,R,D)
 
 
-def _online_softmax_step(s, v_even, v_odd, v_scl, o_ref, m_ref, l_ref,
-                         d2: int):
-    """One kv-tile online-softmax update against the (b, h-block) output.
+def heads_major(x: jax.Array) -> jax.Array:
+    """(bs, Hkv, X) tile -> (Hkv, bs, X)."""
+    return jnp.swapaxes(x, 0, 1)
 
-    s: (bh, G, bs) masked scores; v_even/v_odd: (bs, bh, D/2) decoded
-    value planes; v_scl: (bs, bh) per-token V scale or None (fp caches).
-    """
-    m_prev = m_ref[0]                                      # (bh, G, 1)
+
+def decode_kv_tile(packed: jax.Array) -> jax.Array:
+    """(bs, Hkv, D/2) packed nibbles -> (Hkv, bs, D) f32 codes in plane
+    layout (even K-lanes in [..., :D/2], odd in [..., D/2:])."""
+    spec = ABFLOAT_FOR_NORMAL[KV_NORMAL_DTYPE]
+    even, odd = decode_nibble_planes(packed, KV_NORMAL_DTYPE, spec)
+    return heads_major(jnp.concatenate([even, odd], axis=-1))
+
+
+def online_softmax_step(s, v, v_scl, o_ref, m_ref, l_ref):
+    """One kv-tile online-softmax update of the resident (Hkv, R, D)
+    output block. s: (Hkv, R, bs) masked scores; v: (Hkv, bs, D) values;
+    v_scl: (Hkv, 1, bs) per-token V scale folded into the probabilities,
+    or None (fp caches)."""
+    m_prev = m_ref[0]                                      # (Hkv, R, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)                                 # (bh, G, bs)
-    corr = jnp.exp(m_prev - m_new)                         # (bh, G, 1)
+    p = jnp.exp(s - m_new)                                 # (Hkv, R, bs)
+    corr = jnp.exp(m_prev - m_new)
     l_ref[0] = l_ref[0] * corr + jnp.sum(p, axis=-1, keepdims=True)
     m_ref[0] = m_new
     if v_scl is not None:
-        p = p * jnp.transpose(v_scl)[:, None, :]
-    o_ref[0, :, :, :d2] = o_ref[0, :, :, :d2] * corr + jax.lax.dot_general(
-        p, v_even, _BATCHED_PV, preferred_element_type=jnp.float32)
-    o_ref[0, :, :, d2:] = o_ref[0, :, :, d2:] * corr + jax.lax.dot_general(
-        p, v_odd, _BATCHED_PV, preferred_element_type=jnp.float32)
+        p = p * v_scl
+    o_ref[0] = o_ref[0] * corr + jax.lax.dot_general(
+        p, v, _PV, preferred_element_type=jnp.float32)
+
+
+def init_carry(o_ref, m_ref, l_ref):
+    """Zero the output block and reset the running max / denominator on
+    the first kv tile (grid dim 1 in every attention kernel)."""
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def finish(o_ref, l_ref):
+    """Normalize by the softmax denominator after the last kv tile."""
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _norm():
+        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)
 
 
 def _tile_mask(pos, bs: int, s_len: int, window: int, ring: int):
     """(1, 1, bs) validity of this tile's slots at traced position `pos`."""
-    slot = pl.program_id(2) * bs + jax.lax.broadcasted_iota(
+    slot = pl.program_id(1) * bs + jax.lax.broadcasted_iota(
         jnp.int32, (1, 1, bs), 2)
     if ring:
         abs_pos = pos - ((pos - slot) % ring)
@@ -281,177 +309,115 @@ def _tile_mask(pos, bs: int, s_len: int, window: int, ring: int):
     return valid
 
 
-def _scores(q_tile, k_even, k_odd):
-    """(bh, G, D) query block x (bs, bh, D/2) key planes -> (bh, G, bs)
-    f32 scores (query even lanes live in [..., :D/2], plane layout)."""
-    d2 = k_even.shape[-1]
-    return (jax.lax.dot_general(q_tile[..., :d2], k_even, _BATCHED,
-                                preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(q_tile[..., d2:], k_odd, _BATCHED,
-                                  preferred_element_type=jnp.float32))
+def _scores(q, k, pos_ref, *, bs: int, s_len: int, window: int, ring: int):
+    s = jax.lax.dot_general(q, k, _QK, preferred_element_type=jnp.float32)
+    valid = _tile_mask(pos_ref[pl.program_id(0)], bs, s_len, window, ring)
+    return s, valid
 
 
-def _finish(o_ref, l_ref):
-    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
-    def _norm():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)
+def _decode_attn_kernel_packed(pos_ref, q_ref, kd_ref, vd_ref, ks_ref,
+                               vs_ref, o_ref, m_ref, l_ref, *, bs: int,
+                               s_len: int, window: int, ring: int):
+    """One (batch, kv_tile) grid step over an OVP-packed cache.
 
-
-def _init_carry(o_ref, m_ref, l_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-
-def _decode_attn_kernel_packed(q_ref, kd_ref, vd_ref, ks_ref, vs_ref,
-                               pos_ref, o_ref, m_ref, l_ref, *,
-                               bs: int, s_len: int, window: int, ring: int):
-    """One (batch, head_block, kv_tile) grid step over an OVP-packed cache.
-
-    q_ref  (1, bh, G, D)    f32 query block, pre-scaled by 1/sqrt(D), with
-                            even K-lanes in [..., :D/2] (plane layout)
-    kd/vd  (1, bs, bh, D/2) packed nibble tiles (streamed HBM->VMEM)
-    ks/vs  (1, bs, bh)      per-(token, head) 3-sigma scales
-    pos    (1, 1)           this row's current absolute position
-    o_ref  (1, bh, G, D)    f32 accumulator in even/odd plane layout
-    m/l    (1, bh, G, 1)    online-softmax running max / denominator
+    pos    (B,)              current absolute positions (scalar prefetch)
+    q_ref  (1, Hkv, G, D)    f32 queries, pre-scaled by 1/sqrt(D), in plane
+                             layout (even K-lanes in [..., :D/2])
+    kd/vd  (1, bs, Hkv, D/2) packed nibble tiles (streamed HBM->VMEM)
+    ks/vs  (1, bs, Hkv)      per-(token, head) 3-sigma scales
+    o_ref  (1, Hkv, G, D)    f32 accumulator in plane layout
+    m/l    (1, Hkv, G, 1)    online-softmax running max / denominator
     """
-    _init_carry(o_ref, m_ref, l_ref)
-    spec = ABFLOAT_FOR_NORMAL[KV_NORMAL_DTYPE]
-    k_even, k_odd = decode_nibble_planes(kd_ref[0], KV_NORMAL_DTYPE, spec)
-    v_even, v_odd = decode_nibble_planes(vd_ref[0], KV_NORMAL_DTYPE, spec)
+    init_carry(o_ref, m_ref, l_ref)
     # fold the per-token K scale into the scores, the V scale into the
     # probabilities — the decoded code planes feed the MXU directly
-    s = _scores(q_ref[0], k_even, k_odd) \
-        * jnp.transpose(ks_ref[0])[:, None, :]
-    valid = _tile_mask(pos_ref[0, 0], bs, s_len, window, ring)
-    s = jnp.where(valid, s, NEG_INF)
-    _online_softmax_step(s, v_even, v_odd, vs_ref[0], o_ref, m_ref,
-                         l_ref, k_even.shape[-1])
-    _finish(o_ref, l_ref)
+    s, valid = _scores(q_ref[0], decode_kv_tile(kd_ref[0]), pos_ref, bs=bs,
+                       s_len=s_len, window=window, ring=ring)
+    s = jnp.where(valid, s * jnp.transpose(ks_ref[0])[:, None, :], NEG_INF)
+    online_softmax_step(s, decode_kv_tile(vd_ref[0]),
+                        jnp.transpose(vs_ref[0])[:, None, :], o_ref, m_ref,
+                        l_ref)
+    finish(o_ref, l_ref)
 
 
-def _decode_attn_kernel_fp(q_ref, k_ref, v_ref, pos_ref, o_ref, m_ref,
+def _decode_attn_kernel_fp(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref,
                            l_ref, *, bs: int, s_len: int, window: int,
                            ring: int):
-    """fp16/bf16/f32 cache variant: same body minus the unpack phase —
-    the even/odd planes are strided slices of the fp tile."""
-    _init_carry(o_ref, m_ref, l_ref)
-    kt = k_ref[0].astype(jnp.float32)                      # (bs, bh, D)
-    vt = v_ref[0].astype(jnp.float32)
-    s = _scores(q_ref[0], kt[..., 0::2], kt[..., 1::2])
-    valid = _tile_mask(pos_ref[0, 0], bs, s_len, window, ring)
-    s = jnp.where(valid, s, NEG_INF)
-    _online_softmax_step(s, vt[..., 0::2], vt[..., 1::2], None, o_ref,
-                         m_ref, l_ref, kt.shape[-1] // 2)
-    _finish(o_ref, l_ref)
+    """fp16/bf16/f32 cache variant: same body minus the unpack phase, in
+    natural lane order."""
+    init_carry(o_ref, m_ref, l_ref)
+    kt = heads_major(k_ref[0].astype(jnp.float32))        # (Hkv, bs, D)
+    s, valid = _scores(q_ref[0], kt, pos_ref, bs=bs, s_len=s_len,
+                       window=window, ring=ring)
+    online_softmax_step(jnp.where(valid, s, NEG_INF),
+                        heads_major(v_ref[0].astype(jnp.float32)), None,
+                        o_ref, m_ref, l_ref)
+    finish(o_ref, l_ref)
 
 
 # --------------------------------------------------------------------------
 # pallas_call builder + public wrapper
 # --------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("packed", "s_len", "window",
-                                             "ring", "bs", "bh",
-                                             "interpret"))
-def _decode_attn_call(q4, kd, vd, ks, vs, pos2, *, packed: bool,
+                                             "ring", "bs", "interpret"))
+def _decode_attn_call(bt, pos, q4, kd, vd, ks, vs, *, packed: bool,
                       s_len: int, window: int, ring: int, bs: int,
-                      bh: int, interpret: bool):
-    """q4 (B, Hkv, G, D) f32 plane-layout queries; kd/vd the (padded)
-    cache data; ks/vs (B, Sp, Hkv) scales (fp caches pass (1, 1, 1)
-    sentinels — the fp branch never reads them); pos2 (B, 1) int32.
-    Returns (B, Hkv, G, D) f32 in plane layout."""
+                      interpret: bool):
+    """q4 (B, Hkv, G, D) f32 queries; kd/vd the cache data and ks/vs its
+    (…, Hkv) scales (fp caches pass (1, 1, 1) sentinels the fp body never
+    reads); pos (B,) int32. Returns (B, Hkv, G, D) f32.
+
+    `bt` None: kd/vd are the (padded) slab `(B, Sp, Hkv, …)` and the kv
+    tile is `bs` rows. Otherwise `bt` is the block table: kd/vd/ks/vs are
+    the `(n_pages, page_size, Hkv, …)` pools and the kv/scale index maps
+    read the physical page id from it (a scalar-prefetch operand) instead
+    of using the grid's kv-tile index directly — one whole page is one kv
+    tile, so the gather costs nothing beyond the index indirection, and
+    the kernel bodies are shared verbatim with the slab path."""
     b, hkv, g, d = q4.shape
-    sp = kd.shape[1]
-    grid = (b, hkv // bh, sp // bs)
-    kv_spec = pl.BlockSpec((1, bs, bh, kd.shape[-1]),
-                           lambda bb, hh, ss: (bb, ss, hh, 0))
-    scl_spec = pl.BlockSpec((1, bs, bh), lambda bb, hh, ss: (bb, ss, hh))
-    q_spec = pl.BlockSpec((1, bh, g, d), lambda bb, hh, ss: (bb, hh, 0, 0))
-    pos_spec = pl.BlockSpec((1, 1), lambda bb, hh, ss: (bb, 0))
-    carry_spec = pl.BlockSpec((1, bh, g, 1),
-                              lambda bb, hh, ss: (bb, hh, 0, 0))
+    if bt is None:
+        grid = (b, kd.shape[1] // bs)
+        prefetch = (pos,)
+
+        def tile(bb, ss, *_):
+            return bb, ss
+    else:
+        grid = (b, bt.shape[1])
+        prefetch = (bt, pos)
+
+        def tile(bb, ss, tbl, _):
+            return tbl[bb, ss], 0
+    kv_spec = pl.BlockSpec((1, bs, hkv, kd.shape[-1]),
+                           lambda bb, ss, *r: (*tile(bb, ss, *r), 0, 0))
+    scl_spec = pl.BlockSpec((1, bs, hkv),
+                            lambda bb, ss, *r: (*tile(bb, ss, *r), 0))
+    q_spec = pl.BlockSpec((1, hkv, g, d), lambda bb, ss, *_: (bb, 0, 0, 0))
+    carry_spec = pl.BlockSpec((1, hkv, g, 1),
+                              lambda bb, ss, *_: (bb, 0, 0, 0))
     out_shapes = (jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
                   jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
                   jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32))
-    out_specs = (pl.BlockSpec((1, bh, g, d),
-                              lambda bb, hh, ss: (bb, hh, 0, 0)),
-                 carry_spec, carry_spec)
+    out_specs = (q_spec, carry_spec, carry_spec)
     if packed:
-        kernel = functools.partial(_decode_attn_kernel_packed, bs=bs,
-                                   s_len=s_len, window=window, ring=ring)
-        out, _, _ = pl.pallas_call(
-            kernel, grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec, scl_spec, scl_spec,
-                      pos_spec],
-            out_specs=out_specs, out_shape=out_shapes,
-            interpret=interpret)(q4, kd, vd, ks, vs, pos2)
-    else:
-        kernel = functools.partial(_decode_attn_kernel_fp, bs=bs,
-                                   s_len=s_len, window=window, ring=ring)
-        out, _, _ = pl.pallas_call(
-            kernel, grid=grid,
-            in_specs=[q_spec, kv_spec, kv_spec, pos_spec],
-            out_specs=out_specs, out_shape=out_shapes,
-            interpret=interpret)(q4, kd, vd, pos2)
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("packed", "s_len", "window",
-                                             "ring", "ps", "bh",
-                                             "interpret"))
-def _paged_decode_attn_call(bt, q4, kd, vd, ks, vs, pos2, *, packed: bool,
-                            s_len: int, window: int, ring: int, ps: int,
-                            bh: int, interpret: bool):
-    """Paged twin of `_decode_attn_call`: identical kernel bodies, but the
-    kv/scale BlockSpec index maps read the physical page id from the
-    block table (`bt`, a scalar-prefetch operand) instead of using the
-    grid's kv-tile index directly. kd/vd/ks/vs are the `(n_pages,
-    page_size, Hkv, …)` pools; one whole page == one kv tile, so the
-    gather costs nothing beyond the index indirection."""
-    b, hkv, g, d = q4.shape
-    n = bt.shape[1]
-    grid = (b, hkv // bh, n)
-    kv_spec = pl.BlockSpec((1, ps, bh, kd.shape[-1]),
-                           lambda bb, hh, ss, tbl: (tbl[bb, ss], 0, hh, 0))
-    scl_spec = pl.BlockSpec((1, ps, bh),
-                            lambda bb, hh, ss, tbl: (tbl[bb, ss], 0, hh))
-    q_spec = pl.BlockSpec((1, bh, g, d),
-                          lambda bb, hh, ss, tbl: (bb, hh, 0, 0))
-    pos_spec = pl.BlockSpec((1, 1), lambda bb, hh, ss, tbl: (bb, 0))
-    carry_spec = pl.BlockSpec((1, bh, g, 1),
-                              lambda bb, hh, ss, tbl: (bb, hh, 0, 0))
-    out_shapes = (jax.ShapeDtypeStruct((b, hkv, g, d), jnp.float32),
-                  jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
-                  jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32))
-    out_specs = (pl.BlockSpec((1, bh, g, d),
-                              lambda bb, hh, ss, tbl: (bb, hh, 0, 0)),
-                 carry_spec, carry_spec)
-    if packed:
-        body = functools.partial(_decode_attn_kernel_packed, bs=ps,
+        body = functools.partial(_decode_attn_kernel_packed, bs=bs,
                                  s_len=s_len, window=window, ring=ring)
-
-        def kernel(tbl_ref, *refs):
-            body(*refs)
-
-        in_specs = [q_spec, kv_spec, kv_spec, scl_spec, scl_spec, pos_spec]
-        operands = (bt, q4, kd, vd, ks, vs, pos2)
+        in_specs = [q_spec, kv_spec, kv_spec, scl_spec, scl_spec]
+        operands = (q4, kd, vd, ks, vs)
     else:
-        body = functools.partial(_decode_attn_kernel_fp, bs=ps,
+        body = functools.partial(_decode_attn_kernel_fp, bs=bs,
                                  s_len=s_len, window=window, ring=ring)
+        in_specs = [q_spec, kv_spec, kv_spec]
+        operands = (q4, kd, vd)
 
-        def kernel(tbl_ref, *refs):
-            body(*refs)
+    def kernel(*refs):
+        body(*refs[len(prefetch) - 1:])     # the block table is index-only
 
-        in_specs = [q_spec, kv_spec, kv_spec, pos_spec]
-        operands = (bt, q4, kd, vd, pos2)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
         out_specs=out_specs)
     out, _, _ = pl.pallas_call(kernel, grid_spec=grid_spec,
                                out_shape=out_shapes,
-                               interpret=interpret)(*operands)
+                               interpret=interpret)(*prefetch, *operands)
     return out
 
 
@@ -472,21 +438,33 @@ def _pick_bs(s_len: int, block_s: int) -> int:
     traced decode step — a per-step full-cache HBM round trip that
     defeats the point of the kernel — so exact tiling wins whenever the
     divisor keeps the grid sane; in-kernel masking covers the padded
-    remainder for pathological (e.g. prime) cache lengths."""
+    remainder for pathological (e.g. prime) cache lengths. A tile shorter
+    than the cache is a multiple of 8 rows, as the TPU's (8, 128) block
+    rule asks of the scale tiles' second-to-last dim."""
     bs = min(block_s, s_len)
     if s_len % bs == 0:
         return bs
-    for cand in range(bs, 0, -1):
+    for cand in range(bs - bs % 8, 63, -8):
         if s_len % cand == 0:
-            return cand if cand >= min(64, s_len) else bs
+            return cand
     return bs
+
+
+def to_planes(x: jax.Array) -> jax.Array:
+    """(…, D) -> (…, D) in plane layout: even lanes, then odd lanes."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def from_planes(x: jax.Array) -> jax.Array:
+    """Inverse of `to_planes`: re-interleave the even/odd halves."""
+    d2 = x.shape[-1] // 2
+    return jnp.stack([x[..., :d2], x[..., d2:]], axis=-1).reshape(x.shape)
 
 
 def fused_decode_attention(q: jax.Array, cache, pos: jax.Array, *,
                            window: int = 0, ring: int = 0,
                            interpret: bool = False,
-                           block_s: int = 256,
-                           block_h: int = 0) -> jax.Array:
+                           block_s: int = 256) -> jax.Array:
     """Single-token attention over a KV cache, one pallas_call.
 
     q: (B, 1, H, D); `cache` an fp ({"k", "v"}) or OVP-packed
@@ -496,10 +474,9 @@ def fused_decode_attention(q: jax.Array, cache, pos: jax.Array, *,
     Layout preconditions are `decline_reason`'s job — callers go through
     `backends.decode_attention`, which falls back on a reason code.
 
-    `block_s`/`block_h` tile the kv and head dims. block_h=0 picks the
-    default: 1 head per grid step when compiled (TPU-parallel), all heads
-    per step under the interpreter (amortizes per-grid-step emulation
-    overhead; numerics identical).
+    `block_s` tiles the slab's kv dim (a paged cache's tile is its page).
+    Packed caches decode to the even/odd plane layout, so the queries go
+    in, and the output comes out, through `to_planes` / `from_planes`.
     """
     b, t, h, d = q.shape
     packed = "k_data" in cache
@@ -508,15 +485,11 @@ def fused_decode_attention(q: jax.Array, cache, pos: jax.Array, *,
     vd = cache["v_data"] if packed else cache["v"]
     hkv = kd.shape[2]
     g = h // hkv
-    if block_h == 0:
-        block_h = hkv if interpret else 1
-    bh = min(block_h, hkv)
-    if hkv % bh:
-        bh = 1
     qf = q.reshape(b, hkv, g, d).astype(jnp.float32) / math.sqrt(d)
-    # even/odd plane layout: q[..., :d/2] multiplies the even K-lanes
-    qf = jnp.concatenate([qf[..., 0::2], qf[..., 1::2]], axis=-1)
-    pos2 = pos.reshape(b, 1).astype(jnp.int32)
+    if packed:
+        qf = to_planes(qf)
+    pos = pos.reshape(b).astype(jnp.int32)
+    bt = None
     if paged:
         # page size IS the kv tile size: no padding, no _pick_bs — each
         # grid step gathers one whole physical page through the table.
@@ -524,31 +497,23 @@ def fused_decode_attention(q: jax.Array, cache, pos: jax.Array, *,
         # true length is the ring (the pool rounds it up to whole pages
         # and the mask must exclude the rounding tail).
         bt = cache["block_table"].astype(jnp.int32)
-        ps = kd.shape[1]
-        s_len = ring if ring else bt.shape[1] * ps
-        if packed:
-            ks, vs = cache["k_scl"], cache["v_scl"]
-        else:
-            ks = vs = jnp.zeros((1, 1, 1), jnp.float32)
-        out = _paged_decode_attn_call(bt, qf, kd, vd, ks, vs, pos2,
-                                      packed=packed, s_len=s_len,
-                                      window=window, ring=ring, ps=ps,
-                                      bh=bh, interpret=interpret)
+        bs = kd.shape[1]
+        s_len = ring if ring else bt.shape[1] * bs
     else:
         s_len = kd.shape[1]
         bs = _pick_bs(s_len, block_s)
         kd, vd = _pad_s(kd, bs), _pad_s(vd, bs)
-        if packed:
-            ks = _pad_s(cache["k_scl"], bs, value=1.0)
-            vs = _pad_s(cache["v_scl"], bs, value=1.0)
-        else:
-            # the fp kernel takes no scale refs; tiny sentinels keep the
-            # jitted call signature uniform without materializing scale
-            # planes
-            ks = vs = jnp.zeros((1, 1, 1), jnp.float32)
-        out = _decode_attn_call(qf, kd, vd, ks, vs, pos2, packed=packed,
-                                s_len=s_len, window=window, ring=ring,
-                                bs=bs, bh=bh, interpret=interpret)
-    d2 = d // 2
-    out = jnp.stack([out[..., :d2], out[..., d2:]], axis=-1)
-    return out.reshape(b, hkv, g, d).reshape(b, t, h, d).astype(q.dtype)
+    if packed:
+        ks, vs = cache["k_scl"], cache["v_scl"]
+        if not paged:
+            ks, vs = _pad_s(ks, bs, value=1.0), _pad_s(vs, bs, value=1.0)
+    else:
+        # the fp kernel takes no scale refs; tiny sentinels keep the
+        # jitted call signature uniform without materializing scale planes
+        ks = vs = jnp.zeros((1, 1, 1), jnp.float32)
+    out = _decode_attn_call(bt, pos, qf, kd, vd, ks, vs, packed=packed,
+                            s_len=s_len, window=window, ring=ring, bs=bs,
+                            interpret=interpret)
+    if packed:
+        out = from_planes(out)
+    return out.reshape(b, t, h, d).astype(q.dtype)

@@ -6,7 +6,8 @@ encoder, §3.1: "a thread handles two values simultaneously" — here one VPU
 lane handles one byte = one pair).
 
 Pairs run along the last axis: out byte (r, c) holds u[r, 2c] (high nibble)
-and u[r, 2c+1] (low nibble).
+and u[r, 2c+1] (low nibble). Codes are computed in int32 (Mosaic has no
+shifts on uint8 vectors) and narrowed to bytes on the store.
 """
 from __future__ import annotations
 
@@ -21,7 +22,7 @@ from repro.core.datatypes import ABFLOAT_FOR_NORMAL, AbfloatSpec, NORMAL_MAX
 
 def _encode_normal_int4(u: jax.Array) -> jax.Array:
     q = jnp.clip(jnp.round(u), -7, 7).astype(jnp.int32)
-    return (q & 0xF).astype(jnp.uint8)
+    return q & 0xF
 
 
 def _encode_abfloat4(u: jax.Array, spec: AbfloatSpec) -> jax.Array:
@@ -36,13 +37,17 @@ def _encode_abfloat4(u: jax.Array, spec: AbfloatSpec) -> jax.Array:
     mfield = base & ((1 << spec.mb) - 1)
     code = (sign << 3) | (efield << spec.mb) | mfield
     zero_bits = (efield == 0) & (mfield == 0)
-    return jnp.where(zero_bits, code | 1, code).astype(jnp.uint8)
+    return jnp.where(zero_bits, code | 1, code)
 
 
-def _encode_kernel(u_ref, o_ref, *, spec, nmax):
-    u = u_ref[...].astype(jnp.float32)
-    u0 = u[:, 0::2]
-    u1 = u[:, 1::2]
+def encode_pair_planes(u0: jax.Array, u1: jax.Array,
+                       spec: AbfloatSpec | None = None):
+    """Scaled even/odd value planes -> int32 int4 OVP code planes
+    (Algorithm 1: per pair at most one outlier survives as abfloat, its
+    neighbour becomes the identifier). Shared by the encoder kernel and
+    the fused prefill's cache write; codes match `core.ovp` bit for bit."""
+    spec = ABFLOAT_FOR_NORMAL["int4"] if spec is None else spec
+    nmax = float(NORMAL_MAX["int4"])
     a0, a1 = jnp.abs(u0), jnp.abs(u1)
     o0, o1 = a0 > nmax, a1 > nmax
     first_out = o0 & (~o1 | (a0 >= a1))
@@ -50,10 +55,20 @@ def _encode_kernel(u_ref, o_ref, *, spec, nmax):
 
     n0, n1 = _encode_normal_int4(u0), _encode_normal_int4(u1)
     f0, f1 = _encode_abfloat4(u0, spec), _encode_abfloat4(u1, spec)
-    ident = jnp.uint8(0x8)
+    ident = 0x8
     c0 = jnp.where(first_out, f0, jnp.where(second_out, ident, n0))
     c1 = jnp.where(second_out, f1, jnp.where(first_out, ident, n1))
-    o_ref[...] = (c0 << 4) | (c1 & jnp.uint8(0xF))
+    return c0, c1
+
+
+def pack_pair_planes(c0: jax.Array, c1: jax.Array) -> jax.Array:
+    """int32 code planes -> packed bytes (even code in the high nibble)."""
+    return ((c0 << 4) | (c1 & 0xF)).astype(jnp.uint8)
+
+
+def _encode_kernel(u_ref, o_ref, *, spec):
+    o_ref[...] = pack_pair_planes(*encode_pair_planes(
+        u_ref[0].astype(jnp.float32), u_ref[1].astype(jnp.float32), spec))
 
 
 def ovp_encode_pallas(u: jax.Array, normal_dtype: str = "int4",
@@ -62,20 +77,21 @@ def ovp_encode_pallas(u: jax.Array, normal_dtype: str = "int4",
                       interpret: bool = False) -> jax.Array:
     """u: (M, K) scaled values -> (M, K/2) packed uint8. int4 normals only
     (the serving activation path; flint4 activations are not used by the
-    paper either)."""
+    paper either). The even/odd split runs in XLA before the kernel: an
+    in-kernel lane-strided slice does not lower on the TPU."""
     assert normal_dtype == "int4", "encoder kernel targets int4 activations"
     spec = ABFLOAT_FOR_NORMAL[normal_dtype] if spec is None else spec
     m, k = u.shape
     bm, bk = min(bm, m), min(bk, k)
     bk2 = bk // 2
     grid = (m // bm, (k // 2) // bk2)
-    kernel = functools.partial(_encode_kernel, spec=spec,
-                               nmax=float(NORMAL_MAX[normal_dtype]))
+    planes = jnp.stack([u[:, 0::2], u[:, 1::2]])
+    kernel = functools.partial(_encode_kernel, spec=spec)
     return pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((bm, bk), lambda i, j: (i, j))],
+        in_specs=[pl.BlockSpec((2, bm, bk2), lambda i, j: (0, i, j))],
         out_specs=pl.BlockSpec((bm, bk2), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k // 2), jnp.uint8),
         interpret=interpret,
-    )(u)
+    )(planes)

@@ -18,14 +18,18 @@ kernel*:
               K step (no separate XLA multiply dispatch).
 
 Key structural trick: pairs are packed along K, so a packed tile holds the
-even-K values in the high nibbles and odd-K values in the low nibbles (for
-int8 OVP: even/odd K rows/columns). Instead of interleaving (a relayout),
-we split the reduction:
+even-K values in the high nibbles and odd-K values in the low nibbles.
+Instead of interleaving (a relayout), we split the reduction:
 
     out = a_even @ w_even + a_odd @ w_odd
 
 two half-K MXU matmuls per tile, no transposes, no gathers — this is the
-memory-alignment claim of the paper realised on TPU.
+memory-alignment claim of the paper realised on TPU. Operands that are not
+nibble-packed (fp/quantize activations, int8 codes) reach the kernel as a
+stacked (2, …, K/2) even/odd plane pair: the wrapper deinterleaves them
+with one XLA slice, because Mosaic lowers an in-kernel lane- or
+sublane-strided slice as a gather and refuses it. Packed operands ride
+the same layout with a plane axis of 1.
 
 The grid is (batch, M/bm, N/bn, K2/bk2) with K innermost, so a 3-D lhs
 (decode-step GEMMs from the serving engine) hits the kernel without any
@@ -113,7 +117,8 @@ def decode_pair_planes(c0: jax.Array, c1: jax.Array, normal_dtype: str,
     If my neighbour holds the identifier, I am the outlier (abfloat); if I
     hold it, I am the victim (0); otherwise I am a normal value.
     """
-    ident = jnp.uint8(ID8 if normal_dtype == "int8" else ID4)
+    c0, c1 = c0.astype(jnp.int32), c1.astype(jnp.int32)
+    ident = ID8 if normal_dtype == "int8" else ID4
     dn = _NORMAL_DECODERS[normal_dtype]
 
     def slot(c, neighbour):
@@ -134,9 +139,9 @@ def decode_nibble_planes(packed: jax.Array, normal_dtype: str,
     if normal_dtype == "int8":
         raise ValueError("int8 codes are not nibble-packed; split the code "
                          "planes and use decode_pair_planes directly")
-    hi = (packed >> 4) & jnp.uint8(0xF)
-    lo = packed & jnp.uint8(0xF)
-    return decode_pair_planes(hi, lo, normal_dtype, spec)
+    # widen before shifting: Mosaic has no logical shift on uint8 vectors
+    p = packed.astype(jnp.int32)
+    return decode_pair_planes(p >> 4, p & 0xF, normal_dtype, spec)
 
 
 # --------------------------------------------------------------------------
@@ -194,28 +199,29 @@ def quantize_pair_planes(u0: jax.Array, u1: jax.Array, normal_dtype: str,
 # Shared tile phases (2-D and grouped kernel bodies both use these)
 # --------------------------------------------------------------------------
 def _weight_tile_planes(wp: jax.Array, w_dtype: str, w_spec: AbfloatSpec):
-    """Packed weight tile -> (even, odd) decoded fp32 half-K planes."""
+    """(P, bk2, bn) weight tile -> (even, odd) decoded fp32 half-K planes:
+    int8 code planes (P=2) or packed nibbles (P=1)."""
     if w_dtype == "int8":
-        return decode_pair_planes(wp[0::2, :], wp[1::2, :], "int8", w_spec)
-    return decode_nibble_planes(wp, w_dtype, w_spec)
+        return decode_pair_planes(wp[0], wp[1], "int8", w_spec)
+    return decode_nibble_planes(wp[0], w_dtype, w_spec)
 
 
 def _act_tile_planes(a: jax.Array, sa: jax.Array, a_mode: str,
                      a_dtype: str, a_spec: AbfloatSpec):
-    """Activation prologue: (bm, a_blk) tile -> (even, odd) fp32 planes.
+    """Activation prologue: (P, bm, bk2) tile -> (even, odd) fp32 planes.
 
-    codes4/codes8 decode packed operands; quantize runs the in-kernel OVP
-    fake-quant at the per-row scale `sa`; fp splits the raw tile.
+    codes4 decodes packed nibbles (P=1); codes8 decodes int8 code planes;
+    quantize runs the in-kernel OVP fake-quant at the per-row scale `sa`;
+    fp passes the planes through.
     """
     if a_mode == "codes4":
-        return decode_nibble_planes(a, a_dtype, a_spec)
+        return decode_nibble_planes(a[0], a_dtype, a_spec)
     if a_mode == "codes8":
-        return decode_pair_planes(a[:, 0::2], a[:, 1::2], "int8", a_spec)
-    af = a.astype(jnp.float32)
+        return decode_pair_planes(a[0], a[1], "int8", a_spec)
+    a0, a1 = a[0].astype(jnp.float32), a[1].astype(jnp.float32)
     if a_mode == "quantize":
-        u = af / sa
-        return quantize_pair_planes(u[:, 0::2], u[:, 1::2], a_dtype, a_spec)
-    return af[:, 0::2], af[:, 1::2]  # fp
+        return quantize_pair_planes(a0 / sa, a1 / sa, a_dtype, a_spec)
+    return a0, a1  # fp
 
 
 # --------------------------------------------------------------------------
@@ -226,10 +232,10 @@ def _fused_mm_kernel(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
                      a_mode: str, a_dtype: str, a_spec: AbfloatSpec):
     """One (batch, M, N, K) grid step.
 
-    a_ref  (1, bm, bk)   fp tile (fp/quantize), or codes: (1, bm, bk2)
-                         packed nibbles (codes4) / (1, bm, bk) bytes (codes8)
+    a_ref  (P, 1, bm, bk2)  even/odd planes (fp/quantize/codes8, P=2) or
+                            packed nibbles (codes4, P=1)
     sa_ref (1, bm, 1)    per-row activation scale (1.0 when unscaled)
-    wp_ref (bk2, bn)     packed nibbles, or (bk, bn) int8 OVP codes
+    wp_ref (P, bk2, bn)  packed nibbles (P=1) or int8 OVP code planes (P=2)
     sw_ref (1, bn)       per-output-channel weight scale (1.0 when unscaled)
     o_ref  (1, bm, bn)   fp32 accumulator; scales applied on the last K step
     """
@@ -238,8 +244,8 @@ def _fused_mm_kernel(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     w_even, w_odd = _weight_tile_planes(wp_ref[...], w_dtype, w_spec)
-    a_even, a_odd = _act_tile_planes(a_ref[0], sa_ref[0], a_mode, a_dtype,
-                                     a_spec)
+    a_even, a_odd = _act_tile_planes(a_ref[:, 0], sa_ref[0], a_mode,
+                                     a_dtype, a_spec)
 
     o_ref[0] += (
         jnp.dot(a_even, w_even, preferred_element_type=jnp.float32)
@@ -253,11 +259,12 @@ def _fused_mm_kernel(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
 
 def _act_tile_planes_static(a: jax.Array, a_dtype: str,
                             a_spec: AbfloatSpec, s: jax.Array):
-    """Static-scale activation prologue: OVP fake-quant at the calibrated
-    scalar `s`. One reciprocal per tile instead of a per-row divide, and
-    no (bm, 1) scale tile is ever streamed."""
-    u = a.astype(jnp.float32) * (1.0 / s)
-    return quantize_pair_planes(u[:, 0::2], u[:, 1::2], a_dtype, a_spec)
+    """Static-scale activation prologue: OVP fake-quant of the (2, bm, bk2)
+    planes at the calibrated scalar `s`. One reciprocal per tile instead
+    of a per-row divide, and no (bm, 1) scale tile is ever streamed."""
+    r = 1.0 / s
+    return quantize_pair_planes(a[0].astype(jnp.float32) * r,
+                                a[1].astype(jnp.float32) * r, a_dtype, a_spec)
 
 
 def _fused_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
@@ -271,9 +278,9 @@ def _fused_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
     site/scale, and — upstream — no per-step 3σ std is ever computed.
     This is the serving fast path for `act_scale_mode="static"`.
 
-    a_ref  (1, bm, bk)   fp tile, quantized in-kernel at the scalar scale
+    a_ref  (2, 1, bm, bk2)  fp planes, quantized in-kernel at the scalar
     sa_ref (1, 1)        the calibrated scale (same word on every tile)
-    wp_ref (bk2, bn)     packed nibbles, or (bk, bn) int8 OVP codes
+    wp_ref (P, bk2, bn)  packed nibbles (P=1) or int8 OVP code planes (P=2)
     sw_ref (1, bn)       per-output-channel weight scale
     o_ref  (1, bm, bn)   fp32 accumulator; scales applied on the last K step
     """
@@ -283,7 +290,8 @@ def _fused_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
 
     s = sa_ref[0, 0]
     w_even, w_odd = _weight_tile_planes(wp_ref[...], w_dtype, w_spec)
-    a_even, a_odd = _act_tile_planes_static(a_ref[0], a_dtype, a_spec, s)
+    a_even, a_odd = _act_tile_planes_static(a_ref[:, 0], a_dtype, a_spec,
+                                            s)
 
     o_ref[0] += (
         jnp.dot(a_even, w_even, preferred_element_type=jnp.float32)
@@ -307,9 +315,9 @@ def _grouped_mm_kernel(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
     the full (E, K, N) stack, no global coordination between experts
     (the paper's memory-alignment claim extends to the MoE layout).
 
-    a_ref  (1, 1, bm, a_blk)  one expert's dispatched-slot tile
+    a_ref  (P, 1, 1, bm, bk2) one expert's dispatched-slot planes
     sa_ref (1, 1, bm, 1)      per-slot activation scale
-    wp_ref (1, w_blk, bn)     this expert's packed weight tile
+    wp_ref (P, 1, bk2, bn)    this expert's packed weight tile (planes)
     sw_ref (1, 1, bn)         this expert's per-output-channel scale
     o_ref  (1, 1, bm, bn)     fp32 accumulator, scales on the last K step
     """
@@ -317,8 +325,8 @@ def _grouped_mm_kernel(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w_even, w_odd = _weight_tile_planes(wp_ref[0], w_dtype, w_spec)
-    a_even, a_odd = _act_tile_planes(a_ref[0, 0], sa_ref[0, 0], a_mode,
+    w_even, w_odd = _weight_tile_planes(wp_ref[:, 0], w_dtype, w_spec)
+    a_even, a_odd = _act_tile_planes(a_ref[:, 0, 0], sa_ref[0, 0], a_mode,
                                      a_dtype, a_spec)
 
     o_ref[0, 0] += (
@@ -337,9 +345,9 @@ def _grouped_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
     same scalar-operand prologue/epilogue as `_fused_mm_kernel_static`,
     on the (batch, expert, M, N, K) grid.
 
-    a_ref  (1, 1, bm, bk)  one expert's dispatched-slot fp tile
+    a_ref  (2, 1, 1, bm, bk2)  one expert's dispatched-slot fp planes
     sa_ref (1, 1, 1)       the calibrated scale (same word on every tile)
-    wp_ref (1, w_blk, bn)  this expert's packed weight tile
+    wp_ref (P, 1, bk2, bn) this expert's packed weight tile (planes)
     sw_ref (1, 1, bn)      this expert's per-output-channel scale
     o_ref  (1, 1, bm, bn)  fp32 accumulator, scales on the last K step
     """
@@ -348,9 +356,9 @@ def _grouped_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     s = sa_ref[0, 0, 0]
-    w_even, w_odd = _weight_tile_planes(wp_ref[0], w_dtype, w_spec)
-    a_even, a_odd = _act_tile_planes_static(a_ref[0, 0], a_dtype, a_spec,
-                                            s)
+    w_even, w_odd = _weight_tile_planes(wp_ref[:, 0], w_dtype, w_spec)
+    a_even, a_odd = _act_tile_planes_static(a_ref[:, 0, 0], a_dtype,
+                                            a_spec, s)
 
     o_ref[0, 0] += (
         jnp.dot(a_even, w_even, preferred_element_type=jnp.float32)
@@ -362,8 +370,25 @@ def _grouped_mm_kernel_static(a_ref, sa_ref, wp_ref, sw_ref, o_ref, *,
 
 
 # --------------------------------------------------------------------------
-# pallas_call builder
+# pallas_call wrappers
 # --------------------------------------------------------------------------
+def _act_planes(a: jax.Array, a_mode: str) -> jax.Array:
+    """(…, Ka) activation operand -> (P, …, K/2) plane stack: packed
+    nibbles already hold a pair per byte (P=1); everything else splits
+    into even/odd K planes (P=2) here, in XLA."""
+    if a_mode == "codes4":
+        return a[None]
+    return jnp.stack([a[..., 0::2], a[..., 1::2]])
+
+
+def _weight_planes(w: jax.Array, w_dtype: str) -> jax.Array:
+    """(…, Kw, N) weight codes -> (P, …, K/2, N) plane stack (int8 codes
+    split into even/odd K rows, P=2; packed nibbles P=1)."""
+    if w_dtype == "int8":
+        return jnp.stack([w[..., 0::2, :], w[..., 1::2, :]])
+    return w[None]
+
+
 def fused_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
                             w_data: jax.Array, w_scale: jax.Array, *,
                             w_dtype: str = "int4",
@@ -389,17 +414,16 @@ def fused_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
     w_spec = ABFLOAT_FOR_NORMAL[w_dtype] if w_spec is None else w_spec
     a_spec = ABFLOAT_FOR_NORMAL[a_dtype] if a_spec is None else a_spec
 
-    b, m, ka = a.shape
+    b, m, _ = a.shape
     kw, n = w_data.shape
     k2 = kw if w_dtype != "int8" else kw // 2   # number of pairs along K
     bm, bn = min(bm, m), min(bn, n)
     bk2 = min(bk // 2, k2)
     grid = (b, m // bm, n // bn, k2 // bk2)
 
-    a_blk = bk2 if a_mode == "codes4" else 2 * bk2
-    w_blk = bk2 if w_dtype != "int8" else 2 * bk2
-    assert ka % a_blk == 0 and m % bm == 0 and n % bn == 0 \
-        and kw % w_blk == 0, (a.shape, w_data.shape, (bm, bn, bk2))
+    assert k2 % bk2 == 0 and m % bm == 0 and n % bn == 0, \
+        (a.shape, w_data.shape, (bm, bn, bk2))
+    ap, wp = _act_planes(a, a_mode), _weight_planes(w_data, w_dtype)
 
     if a_static:
         assert a_mode == "quantize", \
@@ -418,16 +442,18 @@ def fused_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bm, a_blk), lambda bb, i, j, kk: (bb, i, kk)),
+            pl.BlockSpec((ap.shape[0], 1, bm, bk2),
+                         lambda bb, i, j, kk: (0, bb, i, kk)),
             sa_spec,
-            pl.BlockSpec((w_blk, bn), lambda bb, i, j, kk: (kk, j)),
+            pl.BlockSpec((wp.shape[0], bk2, bn),
+                         lambda bb, i, j, kk: (0, kk, j)),
             pl.BlockSpec((1, bn), lambda bb, i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((1, bm, bn),
                                lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m, n), jnp.float32),
         interpret=interpret,
-    )(a, a_scale, w_data, w_scale)
+    )(ap, a_scale, wp, w_scale)
 
 
 # --------------------------------------------------------------------------
@@ -458,7 +484,7 @@ def grouped_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
     w_spec = ABFLOAT_FOR_NORMAL[w_dtype] if w_spec is None else w_spec
     a_spec = ABFLOAT_FOR_NORMAL[a_dtype] if a_spec is None else a_spec
 
-    b, e, m, ka = a.shape
+    b, e, m, _ = a.shape
     ew, kw, n = w_data.shape
     assert ew == e, (a.shape, w_data.shape)
     k2 = kw if w_dtype != "int8" else kw // 2   # number of pairs along K
@@ -466,10 +492,9 @@ def grouped_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
     bk2 = min(bk // 2, k2)
     grid = (b, e, m // bm, n // bn, k2 // bk2)
 
-    a_blk = bk2 if a_mode == "codes4" else 2 * bk2
-    w_blk = bk2 if w_dtype != "int8" else 2 * bk2
-    assert ka % a_blk == 0 and m % bm == 0 and n % bn == 0 \
-        and kw % w_blk == 0, (a.shape, w_data.shape, (bm, bn, bk2))
+    assert k2 % bk2 == 0 and m % bm == 0 and n % bn == 0, \
+        (a.shape, w_data.shape, (bm, bn, bk2))
+    ap, wp = _act_planes(a, a_mode), _weight_planes(w_data, w_dtype)
 
     if a_static:
         assert a_mode == "quantize", \
@@ -490,18 +515,18 @@ def grouped_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, bm, a_blk),
-                         lambda bb, ee, i, j, kk: (bb, ee, i, kk)),
+            pl.BlockSpec((ap.shape[0], 1, 1, bm, bk2),
+                         lambda bb, ee, i, j, kk: (0, bb, ee, i, kk)),
             sa_spec,
-            pl.BlockSpec((1, w_blk, bn),
-                         lambda bb, ee, i, j, kk: (ee, kk, j)),
+            pl.BlockSpec((wp.shape[0], 1, bk2, bn),
+                         lambda bb, ee, i, j, kk: (0, ee, kk, j)),
             pl.BlockSpec((1, 1, bn), lambda bb, ee, i, j, kk: (ee, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, 1, bm, bn),
                                lambda bb, ee, i, j, kk: (bb, ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, e, m, n), jnp.float32),
         interpret=interpret,
-    )(a, a_scale, w_data, w_scale)
+    )(ap, a_scale, wp, w_scale)
 
 
 # --------------------------------------------------------------------------

@@ -47,8 +47,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.ovp import ovp_encode_codes, pack4
-from .decode_attn import KV_NORMAL_DTYPE, NEG_INF
+from .decode_attn import (NEG_INF, _QK, finish, from_planes, heads_major,
+                          init_carry, online_softmax_step, to_planes)
+from .ovp_encode import encode_pair_planes, pack_pair_planes
 
 STAGE_KEYS = ("stage_k", "stage_v")
 
@@ -92,127 +93,108 @@ def _prefill_decline_reason(q: jax.Array, cache) -> Optional[str]:
 
 
 # --------------------------------------------------------------------------
-# Kernel bodies: grid (Hkv/bh, n_stage_tiles), kv-tile dim innermost.
+# Kernel bodies: grid (1, n_stage_tiles), kv-tile dim innermost. Blocks
+# carry every kv head (the (8, 128) block rule; see decode_attn.py), and
+# the chunk's queries ride as (Hkv, G*C, D) rows, row = g*C + c.
 # --------------------------------------------------------------------------
-_QK = (((3,), (2,)), ((0,), (1,)))   # (bh,G,C,D) @ (ps,bh,D) -> (bh,G,C,ps)
-_PV = (((3,), (0,)), ((0,), (1,)))   # (bh,G,C,ps) @ (ps,bh,D) -> (bh,G,C,D)
-
-
 def _quant_tile(xt):
-    """(ps, bh, D) raw f32 tile -> (packed (ps, bh, D/2) u8, scale
-    (ps, bh) f32). Identical arithmetic to layers._quant_kv_token, so the
-    page bytes match the slab cache bytes bit-for-bit."""
+    """(ps, Hkv, D) raw f32 tile in plane layout -> (packed (ps, Hkv, D/2)
+    u8, scale (ps, Hkv) f32). Same arithmetic as layers._quant_kv_token
+    (the std sums the lanes in another order, so scales agree to 1 ULP
+    and the nibbles bit for bit)."""
     s = jnp.maximum(3.0 * jnp.std(xt, axis=-1) / 7.0, 1e-6)
-    codes = ovp_encode_codes(xt / s[..., None], KV_NORMAL_DTYPE,
-                             pair_axis=-1)
-    return pack4(codes, pair_axis=-1), s
+    u = xt / s[..., None]
+    d2 = xt.shape[-1] // 2
+    return pack_pair_planes(*encode_pair_planes(u[..., :d2],
+                                                u[..., d2:])), s
 
 
-def _attend_tile(q_ref, kt, vt, off_ref, o_ref, m_ref, l_ref, *, ps: int):
+def _attend_tile(off_ref, q_ref, kt, vt, o_ref, m_ref, l_ref, *, ps: int,
+                 c: int):
     """One online-softmax step of the chunk queries against one raw
-    stage tile, causal on absolute positions (qpos = off + row)."""
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    c = q_ref.shape[3]
+    stage tile, causal on absolute positions (qpos = off + row % C)."""
+    init_carry(o_ref, m_ref, l_ref)
+    r = q_ref.shape[2]
     kpos = pl.program_id(1) * ps + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, ps), 3)
-    qpos = off_ref[0, 0] + jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, c, 1), 2)
-    s = jax.lax.dot_general(q_ref[0], kt, _QK,
+        jnp.int32, (1, 1, ps), 2)
+    qpos = off_ref[0] + jax.lax.broadcasted_iota(jnp.int32, (1, r, 1), 1) % c
+    s = jax.lax.dot_general(q_ref[0], heads_major(kt), _QK,
                             preferred_element_type=jnp.float32)
-    s = jnp.where(kpos <= qpos, s, NEG_INF)        # (bh, G, C, ps)
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[0] = l_ref[0] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[0] = m_new
-    o_ref[0] = o_ref[0] * corr + jax.lax.dot_general(
-        p, vt, _PV, preferred_element_type=jnp.float32)
-
-    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
-    def _norm():
-        o_ref[0] = o_ref[0] / jnp.maximum(l_ref[0], 1e-30)
+    online_softmax_step(jnp.where(kpos <= qpos, s, NEG_INF),
+                        heads_major(vt), None, o_ref, m_ref, l_ref)
+    finish(o_ref, l_ref)
 
 
-def _prefill_kernel_packed(tbl_ref, q_ref, ksg_ref, vsg_ref, off_ref,
+def _prefill_kernel_packed(tbl_ref, off_ref, q_ref, ksg_ref, vsg_ref,
                            kdp_ref, vdp_ref, ksp_ref, vsp_ref,
                            o_ref, m_ref, l_ref,
-                           kd_ref, vd_ref, ks_ref, vs_ref, *, ps: int):
-    """q (1,bh,G,C,D) pre-scaled; ksg/vsg (1,ps,bh,D) raw stage tiles;
-    kd/vd/ks/vs out blocks land on page tbl[0, tile] (aliased pool)."""
+                           kd_ref, vd_ref, ks_ref, vs_ref, *, ps: int,
+                           c: int):
+    """q (1,Hkv,G*C,D) pre-scaled; ksg/vsg (1,ps,Hkv,D) raw stage tiles,
+    both in plane layout; kd/vd/ks/vs out blocks land on page
+    tbl[0, tile] (aliased pool)."""
     kt = ksg_ref[0].astype(jnp.float32)
     vt = vsg_ref[0].astype(jnp.float32)
     kd_ref[0], ks_ref[0] = _quant_tile(kt)
     vd_ref[0], vs_ref[0] = _quant_tile(vt)
-    _attend_tile(q_ref, kt, vt, off_ref, o_ref, m_ref, l_ref, ps=ps)
+    _attend_tile(off_ref, q_ref, kt, vt, o_ref, m_ref, l_ref, ps=ps, c=c)
 
 
-def _prefill_kernel_fp(tbl_ref, q_ref, ksg_ref, vsg_ref, off_ref,
+def _prefill_kernel_fp(tbl_ref, off_ref, q_ref, ksg_ref, vsg_ref,
                        kp_ref, vp_ref, o_ref, m_ref, l_ref,
-                       k_ref, v_ref, *, ps: int):
+                       k_ref, v_ref, *, ps: int, c: int):
     kt = ksg_ref[0].astype(jnp.float32)
     vt = vsg_ref[0].astype(jnp.float32)
     k_ref[0] = kt.astype(k_ref.dtype)
     v_ref[0] = vt.astype(v_ref.dtype)
-    _attend_tile(q_ref, kt, vt, off_ref, o_ref, m_ref, l_ref, ps=ps)
+    _attend_tile(off_ref, q_ref, kt, vt, o_ref, m_ref, l_ref, ps=ps, c=c)
 
 
 # --------------------------------------------------------------------------
 # pallas_call builder + public wrappers
 # --------------------------------------------------------------------------
 @functools.partial(jax.jit, static_argnames=("packed", "ps", "n_tiles",
-                                             "bh", "interpret"))
-def _prefill_call(bt, q5, ksg, vsg, off2, pools, *, packed: bool, ps: int,
-                  n_tiles: int, bh: int, interpret: bool):
-    """q5 (1, Hkv, G, C, D) f32 pre-scaled; ksg/vsg (1, S, Hkv, D) raw
-    stage; off2 (1, 1) chunk offset; pools the pool leaves (aliased
-    through to the outputs). Returns (out5, new_pools)."""
-    _, hkv, g, c, d = q5.shape
-    grid = (hkv // bh, n_tiles)
-    q_spec = pl.BlockSpec((1, bh, g, c, d),
-                          lambda hh, ss, tbl: (0, hh, 0, 0, 0))
-    stage_spec = pl.BlockSpec((1, ps, bh, d),
-                              lambda hh, ss, tbl: (0, ss, hh, 0))
-    off_spec = pl.BlockSpec((1, 1), lambda hh, ss, tbl: (0, 0))
-    carry_spec = pl.BlockSpec((1, bh, g, c, 1),
-                              lambda hh, ss, tbl: (0, hh, 0, 0, 0))
-    o_spec = pl.BlockSpec((1, bh, g, c, d),
-                          lambda hh, ss, tbl: (0, hh, 0, 0, 0))
-    page_spec = pl.BlockSpec((1, ps, bh, pools[0].shape[-1]),
-                             lambda hh, ss, tbl: (tbl[0, ss], 0, hh, 0))
-    scl_spec = pl.BlockSpec((1, ps, bh),
-                            lambda hh, ss, tbl: (tbl[0, ss], 0, hh))
-    carry_shape = jax.ShapeDtypeStruct((1, hkv, g, c, 1), jnp.float32)
-    o_shape = jax.ShapeDtypeStruct((1, hkv, g, c, d), jnp.float32)
+                                             "c", "interpret"))
+def _prefill_call(bt, off, q4, ksg, vsg, pools, *, packed: bool, ps: int,
+                  n_tiles: int, c: int, interpret: bool):
+    """q4 (1, Hkv, G*C, D) f32 pre-scaled; ksg/vsg (1, S, Hkv, D) raw
+    stage; off (1,) chunk offset; pools the pool leaves (aliased through
+    to the outputs). Returns (out4, new_pools)."""
+    _, hkv, r, d = q4.shape
+    grid = (1, n_tiles)
+    q_spec = pl.BlockSpec((1, hkv, r, d), lambda i, ss, *_: (0, 0, 0, 0))
+    stage_spec = pl.BlockSpec((1, ps, hkv, d),
+                              lambda i, ss, *_: (0, ss, 0, 0))
+    carry_spec = pl.BlockSpec((1, hkv, r, 1), lambda i, ss, *_: (0, 0, 0, 0))
+    page_spec = pl.BlockSpec((1, ps, hkv, pools[0].shape[-1]),
+                             lambda i, ss, tbl, _: (tbl[0, ss], 0, 0, 0))
+    scl_spec = pl.BlockSpec((1, ps, hkv),
+                            lambda i, ss, tbl, _: (tbl[0, ss], 0, 0))
+    carry_shape = jax.ShapeDtypeStruct((1, hkv, r, 1), jnp.float32)
+    o_shape = jax.ShapeDtypeStruct((1, hkv, r, d), jnp.float32)
     pool_shapes = tuple(jax.ShapeDtypeStruct(p.shape, p.dtype)
                         for p in pools)
     pool_specs = tuple(scl_spec if p.ndim == 3 else page_spec
                        for p in pools)
     kernel = functools.partial(
-        _prefill_kernel_packed if packed else _prefill_kernel_fp, ps=ps)
-    # pool operands sit after (bt, q5, ksg, vsg, off2); their outputs
+        _prefill_kernel_packed if packed else _prefill_kernel_fp, ps=ps, c=c)
+    # pool operands sit after (bt, off, q4, ksg, vsg); their outputs
     # after (o, m, l) — aliasing keeps pages no stage tile touches intact
     aliases = {5 + i: 3 + i for i in range(len(pools))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid,
-        in_specs=[q_spec, stage_spec, stage_spec, off_spec, *pool_specs],
-        out_specs=(o_spec, carry_spec, carry_spec, *pool_specs))
+        num_scalar_prefetch=2, grid=grid,
+        in_specs=[q_spec, stage_spec, stage_spec, *pool_specs],
+        out_specs=(q_spec, carry_spec, carry_spec, *pool_specs))
     res = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=(o_shape, carry_shape, carry_shape, *pool_shapes),
         input_output_aliases=aliases,
-        interpret=interpret)(bt, q5, ksg, vsg, off2, *pools)
+        interpret=interpret)(bt, off, q4, ksg, vsg, *pools)
     return res[0], res[3:]
 
 
 def fused_prefill_attention(q: jax.Array, cache, positions: jax.Array, *,
-                            interpret: bool = False,
-                            block_h: int = 0) -> Tuple[jax.Array, dict]:
+                            interpret: bool = False) -> Tuple[jax.Array, dict]:
     """One pallas_call: causal attention of the chunk over the raw stage
     + OVP quantize-and-write of every stage tile onto its physical page.
 
@@ -225,31 +207,34 @@ def fused_prefill_attention(q: jax.Array, cache, positions: jax.Array, *,
     updated pool leaves). Layout preconditions are
     `prefill_decline_reason`'s job — callers go through
     `backends.prefill_attention`.
+
+    A packed cache encodes pairs of adjacent lanes, so the stage and the
+    queries enter in the even/odd plane layout (`to_planes`) and the
+    output leaves through `from_planes`; fp caches stay in natural order.
     """
     b, c, h, d = q.shape
     packed = "k_data" in cache
-    stage_k, stage_v = cache["stage_k"], cache["stage_v"]
+    stage_k = cache["stage_k"].astype(jnp.float32)
+    stage_v = cache["stage_v"].astype(jnp.float32)
     s, hkv = stage_k.shape[1], stage_k.shape[2]
     pool_keys = ("k_data", "v_data", "k_scl", "v_scl") if packed \
         else ("k", "v")
     pools = tuple(cache[key] for key in pool_keys)
     ps = pools[0].shape[1]
-    n_tiles = s // ps
     g = h // hkv
-    if block_h == 0:
-        block_h = hkv if interpret else 1
-    bh = min(block_h, hkv)
-    if hkv % bh:
-        bh = 1
-    q5 = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4) \
-        .astype(jnp.float32) / math.sqrt(d)
+    q4 = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4) \
+        .reshape(b, hkv, g * c, d).astype(jnp.float32) / math.sqrt(d)
+    if packed:
+        q4, stage_k, stage_v = (to_planes(x) for x in (q4, stage_k, stage_v))
     bt = cache["block_table"].astype(jnp.int32)
-    off2 = positions[:, :1].astype(jnp.int32)
-    out5, new_pools = _prefill_call(
-        bt, q5, stage_k.astype(jnp.float32), stage_v.astype(jnp.float32),
-        off2, pools, packed=packed, ps=ps, n_tiles=n_tiles, bh=bh,
-        interpret=interpret)
-    out = out5.transpose(0, 3, 1, 2, 4).reshape(b, c, h, d).astype(q.dtype)
+    off = positions[0, :1].astype(jnp.int32)
+    out4, new_pools = _prefill_call(
+        bt, off, q4, stage_k, stage_v, pools, packed=packed, ps=ps,
+        n_tiles=s // ps, c=c, interpret=interpret)
+    if packed:
+        out4 = from_planes(out4)
+    out = out4.reshape(b, hkv, g, c, d).transpose(0, 3, 1, 2, 4) \
+        .reshape(b, c, h, d).astype(q.dtype)
     new_cache = dict(cache)
     for key, pool in zip(pool_keys, new_pools):
         new_cache[key] = pool

@@ -9,16 +9,10 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-
-try:  # jax >= 0.4.31; older releases predate explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _axis_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n_axes}
 
 

@@ -38,6 +38,16 @@ JSONL metrics trace the benchmarks consume:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen1.5-0.5b-smoke \
       --quant olive_serve --paged 16 --prefill-chunk 32 --requests 16 \
       --async --stream --metrics-out /tmp/serve_trace.jsonl
+
+Presets are served as named (olive_serve: bf16 compute, W4A4 at dynamic
+activation scales, 4-bit OVP KV cache). On a CPU, pick
+`--backend pallas_interpret` (the kernels under the Pallas interpreter) or
+`--backend xla`; `chip_smoke.py` at the repo root drives the same helpers
+on a TPU.
+
+The helpers below (`build_policy`, `init_model`, `make_engine`,
+`make_prompts`) are the whole set-up path; `main` and `chip_smoke.py` both
+call them, so the smoke serves exactly what this CLI serves.
 """
 from __future__ import annotations
 
@@ -45,6 +55,8 @@ import argparse
 import asyncio
 import os
 import time
+from pathlib import Path
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +74,85 @@ from repro.serve.engine import EngineCfg, Request, ServingEngine
 from repro.serve.frontend import AsyncFrontend
 from repro.serve.metrics import MetricsLedger
 from repro.serve.paging import PagePoolCfg
+
+REPO_ROOT = Path(__file__).resolve().parents[3]
+
+
+def configure_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    Where `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and no
+    other directory is set here. Otherwise the cache lives at the fixed
+    `.jax_cache/` of this checkout (git-ignored): the path is part of the
+    cache key, so a moving directory would never hit. Called by entry
+    points only, never at import time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_policy(cfg, quant: str, *, policy_rules: Optional[str] = None,
+                 backend: Optional[str] = None, calibration: bool = False):
+    """The preset (or policy program) `quant` as named, with the
+    `--policy-rules` overlay and the backend override. A calibration
+    artifact switches every site to static activation scales."""
+    if quant in PROGRAM_PRESETS or policy_rules:
+        policy = get_program(None if quant == "fp" else quant,
+                             n_layers=cfg.n_layers)
+        if policy_rules:
+            policy = policy.with_rules(parse_rules(policy_rules))
+    else:
+        policy = get_policy(None if quant == "fp" else quant)
+    if calibration:
+        policy = policy.replace_all(act_scale_mode="static")
+    if backend is not None:
+        policy = policy.with_backend(backend)
+    return policy
+
+
+def init_model(cfg, policy, seed: int = 0):
+    """(model, f32 master params) drawn from `seed`."""
+    model = build_model(cfg, policy, remat=False)
+    return model, model.init(jax.random.PRNGKey(seed), dtype=jnp.float32)
+
+
+def parse_mesh(spec: str):
+    """'DATA,MODEL' -> a `MeshPlan` over the first DATA*MODEL devices."""
+    from repro.runtime.elastic import MeshPlan
+    sizes = tuple(int(v) for v in spec.split(","))
+    if len(sizes) != 2 or any(v < 1 for v in sizes):
+        raise ValueError(f"--mesh wants two positive sizes 'data,model', "
+                         f"got {spec!r}")
+    if sizes[0] * sizes[1] > jax.device_count():
+        raise ValueError(f"--mesh {spec} needs {sizes[0] * sizes[1]} "
+                         f"devices, have {jax.device_count()}")
+    return MeshPlan(shape=sizes, axis_names=("data", "model"),
+                    dropped_devices=0)
+
+
+def make_engine(model, params, *, slots: int, max_len: int,
+                page_size: int = 0, prefill_chunk: int = 0, mesh=None,
+                backend: Optional[str] = None,
+                keep_prefill_logits: bool = False) -> ServingEngine:
+    """The continuous-batching engine: slab cache, or a paged pool of
+    `page_size` pages with chunked prefill."""
+    page_pool = PagePoolCfg(page_size=page_size) if page_size else None
+    return ServingEngine(model, params, EngineCfg(
+        batch_slots=slots, max_len=max_len, page_pool=page_pool,
+        prefill_chunk=prefill_chunk, mesh=mesh, backend=backend,
+        keep_prefill_logits=keep_prefill_logits))
+
+
+def make_prompts(vocab: int, n: int, min_len: int, max_len: int,
+                 seed: int = 0) -> List[np.ndarray]:
+    """`n` seeded prompts of uniform random tokens, lengths drawn from
+    [min_len, max_len)."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, size=int(rng.integers(min_len, max_len)))
+            .astype(np.int32) for _ in range(n)]
 
 
 async def _serve_async(eng, prompts, max_new, metrics, stream_tokens):
@@ -161,29 +252,14 @@ def main():
         ap.error("--stream requires --async (the drained loop has no "
                  "token streams)")
 
+    configure_compile_cache()
     cfg = get_config(args.arch)
-    if args.quant in PROGRAM_PRESETS or args.policy_rules:
-        policy = get_program(None if args.quant == "fp" else args.quant,
-                             n_layers=cfg.n_layers)
-        if args.policy_rules:
-            policy = policy.with_rules(parse_rules(args.policy_rules))
-    else:
-        policy = get_policy(None if args.quant == "fp" else args.quant)
-    # CPU engine default: weight + KV quant only (replace_all rewrites
-    # every rule of a program, or the one flat policy). A calibration
-    # artifact keeps the preset's abits — static scales exist precisely to
-    # serve quantized activations without per-step scale computation.
-    if args.calibration:
-        policy = policy.replace_all(compute_dtype="float32",
-                                    act_scale_mode="static")
-    else:
-        policy = policy.replace_all(compute_dtype="float32", abits=0)
-    if args.backend is not None:
-        policy = policy.with_backend(args.backend)
+    policy = build_policy(cfg, args.quant, policy_rules=args.policy_rules,
+                          backend=args.backend,
+                          calibration=bool(args.calibration))
     print(f"[serve] quantized-matmul backend(s): "
           f"{', '.join(sorted(policy.backends()))}")
-    model = build_model(cfg, policy, remat=False)
-    params = model.init(jax.random.PRNGKey(args.seed), dtype=jnp.float32)
+    model, params = init_model(cfg, policy, args.seed)
 
     if args.calibration:
         if args.calibrate:
@@ -215,30 +291,19 @@ def main():
 
     mesh_plan = None
     if args.mesh:
-        from repro.runtime.elastic import MeshPlan
-        sizes = tuple(int(s) for s in args.mesh.split(","))
-        if len(sizes) != 2 or any(s < 1 for s in sizes):
-            ap.error(f"--mesh wants two positive sizes 'data,model', "
-                     f"got {args.mesh!r}")
-        if sizes[0] * sizes[1] > jax.device_count():
-            ap.error(f"--mesh {args.mesh} needs {sizes[0] * sizes[1]} "
-                     f"devices, have {jax.device_count()} (set "
-                     f"XLA_FLAGS=--xla_force_host_platform_device_count"
-                     f"=N before launch)")
-        mesh_plan = MeshPlan(shape=sizes, axis_names=("data", "model"),
-                             dropped_devices=0)
-        print(f"[serve] mesh: data={sizes[0]} model={sizes[1]} over "
-              f"{jax.device_count()} devices")
+        try:
+            mesh_plan = parse_mesh(args.mesh)
+        except ValueError as e:
+            ap.error(f"{e} (on a CPU, set XLA_FLAGS=--xla_force_host_"
+                     f"platform_device_count=N before launch)")
+        print(f"[serve] mesh: data={mesh_plan.shape[0]} "
+              f"model={mesh_plan.shape[1]} over {jax.device_count()} "
+              f"devices")
 
-    page_pool = PagePoolCfg(page_size=args.paged) if args.paged else None
-    eng = ServingEngine(model, params, EngineCfg(
-        batch_slots=args.slots, max_len=args.max_len,
-        page_pool=page_pool, prefill_chunk=args.prefill_chunk,
-        mesh=mesh_plan))
-    rng = np.random.default_rng(args.seed)
-    prompts = [rng.integers(0, cfg.vocab,
-                            size=int(rng.integers(4, 32)))
-               .astype(np.int32) for _ in range(args.requests)]
+    eng = make_engine(model, params, slots=args.slots, max_len=args.max_len,
+                      page_size=args.paged, prefill_chunk=args.prefill_chunk,
+                      mesh=mesh_plan)
+    prompts = make_prompts(cfg.vocab, args.requests, 4, 32, args.seed)
     metrics = MetricsLedger() if (args.metrics_out or args.use_async) \
         else None
     t0 = time.time()

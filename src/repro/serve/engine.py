@@ -73,6 +73,9 @@ class Request:
     t_submit: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
+    # next-token logits of the prompt's last position, on the host
+    # (kept only with EngineCfg.keep_prefill_logits)
+    prefill_logits: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -168,6 +171,10 @@ class EngineCfg:
     # mesh untouched — without one those backends decline every call with
     # `shard_no_mesh` and serve through their dense fallback.
     mesh: Optional[object] = None
+    # copy each request's prefill logits to the host (Request.
+    # prefill_logits): lets a check compare backends on the served path.
+    # Off by default — one vocab-wide row per request.
+    keep_prefill_logits: bool = False
 
 
 class ServingEngine:
@@ -453,13 +460,20 @@ class ServingEngine:
                     jnp.int32(t))
                 self.caches = _splice_slot(self.caches, row_cache, s)
                 self.pos[s] = t
-                nxt = int(jnp.argmax(logits[0]))
-                req.out_tokens.append(nxt)
-                req.t_first = time.monotonic()
+                nxt = self._first_token(req, logits)
                 finished = self._finish_at_admit(req, nxt)
                 self._emit_token(req, nxt, first=True)
                 if not finished:
                     self.slots[s] = req
+
+    def _first_token(self, req: Request, logits) -> int:
+        """Greedy first token from the prefill logits (1, vocab)."""
+        if self.cfg.keep_prefill_logits:
+            req.prefill_logits = np.asarray(logits[0], np.float32)
+        nxt = int(jnp.argmax(logits[0]))
+        req.out_tokens.append(nxt)
+        req.t_first = time.monotonic()
+        return nxt
 
     def _finish_at_admit(self, req: Request, nxt: int) -> bool:
         """The prefill token already satisfies the budget (or hit EOS):
@@ -571,9 +585,7 @@ class ServingEngine:
         self._bt[s, :] = 0
         self._bt[s, :pf.gen_pages] = pf.pages[:pf.gen_pages]
         self._sync_tables()
-        nxt = int(jnp.argmax(logits[0]))
-        req.out_tokens.append(nxt)
-        req.t_first = time.monotonic()
+        nxt = self._first_token(req, logits)
         finished = self._finish_at_admit(req, nxt)
         self._emit_token(req, nxt, first=True)
         if finished:

@@ -132,12 +132,14 @@ def test_non_divisible_cache_length_avoids_per_step_pad():
     """A cache length that is no multiple of block_s must tile on an
     exact divisor when a sane one exists (a non-divisor tile would copy
     the whole cache through jnp.pad every traced decode step) — and stay
-    correct either way."""
-    assert DA._pick_bs(300, 256) == 150      # exact divisor, no padding
+    correct either way. A tile shorter than the cache is a multiple of 8
+    rows (the TPU block rule for the scale tiles)."""
+    assert DA._pick_bs(384, 256) == 192      # exact divisor, no padding
     assert DA._pick_bs(1024, 256) == 256
     assert DA._pick_bs(1021, 256) == 256     # prime: pad + in-kernel mask
+    assert DA._pick_bs(300, 256) == 256      # no 8-aligned divisor: pad
     rng = np.random.default_rng(9)
-    for s in (300, 97):                      # divisor-tiled and padded
+    for s in (384, 300, 97):                 # divisor-tiled and padded
         cache = _mk_cache(rng, 2, s, 2, 8, 4)
         q = jnp.asarray(rng.standard_normal((2, 1, 4, 8)), jnp.float32)
         pos = jnp.asarray([s // 3, s - 1], jnp.int32)

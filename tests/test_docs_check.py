@@ -11,8 +11,8 @@ in-tree `README.md`s under `src/` — and fails on:
   `::symbol` suffix) but point at nothing — paths resolve against the
   doc's own directory, the repo root, `src/`, and `src/repro/`;
 - `--flag` tokens that no argparse definition in `src/repro/launch/`,
-  `src/repro/analysis/`, or `benchmarks/` declares (docs describing
-  nonexistent CLI flags).
+  `src/repro/analysis/`, `benchmarks/` or `chip_smoke.py` declares
+  (docs describing nonexistent CLI flags).
 
 Pure stdlib + grep-style regexes: no markdown parser dependency.
 """
@@ -118,13 +118,15 @@ def _declared_cli_flags() -> set:
                     REPO / "benchmarks"]:
         for py in src_dir.glob("*.py"):
             flags.update(ARGPARSE_FLAG_RE.findall(py.read_text()))
+    flags.update(ARGPARSE_FLAG_RE.findall(
+        (REPO / "chip_smoke.py").read_text()))
     return flags
 
 
 @pytest.mark.parametrize("md", DOC_FILES, ids=lambda p: str(p.relative_to(REPO)))
 def test_cli_flags_exist(md):
     """Every --flag a doc mentions must be declared by some argparse in
-    launch/, analysis/, or benchmarks/ — docs referencing removed or
+    launch/, analysis/, benchmarks/ or chip_smoke.py — docs referencing removed or
     misspelled flags fail here (checked inside code fences too: that's
     where the copy-paste commands live)."""
     declared = _declared_cli_flags()
