@@ -415,9 +415,11 @@ def _decode_attn_call(bt, pos, q4, kd, vd, ks, vs, *, packed: bool,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch), grid=grid, in_specs=in_specs,
         out_specs=out_specs)
+    # `name` is the op's name in the device trace (`_decode_attn_call.<n>`),
+    # whatever function wraps the call
     out, _, _ = pl.pallas_call(kernel, grid_spec=grid_spec,
-                               out_shape=out_shapes,
-                               interpret=interpret)(*prefetch, *operands)
+                               out_shape=out_shapes, interpret=interpret,
+                               name="_decode_attn_call")(*prefetch, *operands)
     return out
 
 

@@ -87,6 +87,7 @@ def ovp_encode_pallas(u: jax.Array, normal_dtype: str = "int4",
     grid = (m // bm, (k // 2) // bk2)
     planes = jnp.stack([u[:, 0::2], u[:, 1::2]])
     kernel = functools.partial(_encode_kernel, spec=spec)
+    # `name` is the op's name in the device trace (`ovp_encode.<n>`)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -94,4 +95,5 @@ def ovp_encode_pallas(u: jax.Array, normal_dtype: str = "int4",
         out_specs=pl.BlockSpec((bm, bk2), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, k // 2), jnp.uint8),
         interpret=interpret,
+        name="ovp_encode",
     )(planes)

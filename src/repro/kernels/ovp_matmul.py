@@ -438,6 +438,9 @@ def fused_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
                                    w_spec=w_spec, a_mode=a_mode,
                                    a_dtype=a_dtype, a_spec=a_spec)
         sa_spec = pl.BlockSpec((1, bm, 1), lambda bb, i, j, kk: (bb, i, 0))
+    # `name` is the op's name in the device trace (`_fused_padded.<n>`, the
+    # name of the jitted wrapper in kernels/ops.py that every served call
+    # goes through, and the prefix perfbench's ovp_matmul_roofline finds)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -453,6 +456,7 @@ def fused_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
                                lambda bb, i, j, kk: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m, n), jnp.float32),
         interpret=interpret,
+        name="_fused_padded",
     )(ap, a_scale, wp, w_scale)
 
 
@@ -511,6 +515,7 @@ def grouped_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
                                    a_dtype=a_dtype, a_spec=a_spec)
         sa_spec = pl.BlockSpec((1, 1, bm, 1),
                                lambda bb, ee, i, j, kk: (bb, ee, i, 0))
+    # `name` is the op's name in the device trace (`_grouped_padded.<n>`)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -526,6 +531,7 @@ def grouped_ovp_matmul_kernel(a: jax.Array, a_scale: jax.Array,
                                lambda bb, ee, i, j, kk: (bb, ee, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, e, m, n), jnp.float32),
         interpret=interpret,
+        name="_grouped_padded",
     )(ap, a_scale, wp, w_scale)
 
 

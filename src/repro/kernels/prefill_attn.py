@@ -185,11 +185,13 @@ def _prefill_call(bt, off, q4, ksg, vsg, pools, *, packed: bool, ps: int,
         num_scalar_prefetch=2, grid=grid,
         in_specs=[q_spec, stage_spec, stage_spec, *pool_specs],
         out_specs=(q_spec, carry_spec, carry_spec, *pool_specs))
+    # `name` is the op's name in the device trace (`_prefill_call.<n>`)
     res = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=(o_shape, carry_shape, carry_shape, *pool_shapes),
         input_output_aliases=aliases,
-        interpret=interpret)(bt, off, q4, ksg, vsg, *pools)
+        interpret=interpret, name="_prefill_call")(
+            bt, off, q4, ksg, vsg, *pools)
     return res[0], res[3:]
 
 

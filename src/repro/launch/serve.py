@@ -351,7 +351,7 @@ def main():
                     f"p50={d['p50']*1e3:.1f}ms p95={d['p95']*1e3:.1f}ms")
 
         print(f"[serve] SLO: TTFT {_fmt(snap['ttft_s'])} | "
-              f"TPOT {_fmt(snap['tpot_s'])}")
+              f"TPOT {_fmt(snap['tpot_s'])} | ITL {_fmt(snap['itl_s'])}")
         print(f"[serve] {snap['steps']} steps, fallbacks={snap['fallbacks']}"
               + (f", interleave={snap['prefill_interleave_ratio']:.2f}"
                  if snap["prefill_interleave_ratio"] is not None else ""))

@@ -41,6 +41,7 @@ Slots free their pages on completion; `defrag()` compacts the pool.
 from __future__ import annotations
 
 import collections
+import contextlib
 import copy
 import dataclasses
 import time
@@ -113,6 +114,10 @@ class StepEvents:
     queue_depth: int            # queued requests AFTER the step
     active: int                 # occupied decode slots after the step
     prefilling: int             # requests mid-chunked-prefill after the step
+    # host seconds of each `engine.<phase>` span this step (a nested
+    # phase, such as "tables", also counts in the phase that called it)
+    phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    table_uploads: int = 0      # block-table uploads (`_sync_tables`)
 
 
 @dataclasses.dataclass
@@ -255,6 +260,8 @@ class ServingEngine:
         # `step()` returns (see the StepEvents docstring)
         self._token_events: List[TokenEvent] = []
         self._admitted_uids: List[int] = []
+        self._phases: Dict[str, float] = {}
+        self._table_uploads = 0
 
         self.paged = cfg.page_pool is not None
         if self.paged:
@@ -404,15 +411,17 @@ class ServingEngine:
         """Push the host block table into every cache site (scan-stacked
         sites broadcast the same table across groups — page ids back the
         same token rows in every layer)."""
-        bt = jnp.asarray(self._bt)
+        self._table_uploads += 1
+        with self._phase("tables"):
+            bt = jnp.asarray(self._bt)
 
-        def set_bt(site):
-            cur = site["block_table"]
-            new = bt if cur.ndim == 2 else \
-                jnp.broadcast_to(bt[None], cur.shape)
-            return dict(site, block_table=new)
+            def set_bt(site):
+                cur = site["block_table"]
+                new = bt if cur.ndim == 2 else \
+                    jnp.broadcast_to(bt[None], cur.shape)
+                return dict(site, block_table=new)
 
-        self.caches = self._map_sites(self.caches, set_bt)
+            self.caches = self._map_sites(self.caches, set_bt)
 
     def _fresh_stage(self, site, stage_len: int):
         cfg = self.model.cfg
@@ -467,10 +476,12 @@ class ServingEngine:
                     self.slots[s] = req
 
     def _first_token(self, req: Request, logits) -> int:
-        """Greedy first token from the prefill logits (1, vocab)."""
-        if self.cfg.keep_prefill_logits:
-            req.prefill_logits = np.asarray(logits[0], np.float32)
-        nxt = int(jnp.argmax(logits[0]))
+        """Greedy first token from the prefill logits (1, vocab): a device
+        sync, before the step's decode is dispatched."""
+        with self._phase("first_token"):
+            if self.cfg.keep_prefill_logits:
+                req.prefill_logits = np.asarray(logits[0], np.float32)
+            nxt = int(jnp.argmax(logits[0]))
         req.out_tokens.append(nxt)
         req.t_first = time.monotonic()
         return nxt
@@ -539,6 +550,24 @@ class ServingEngine:
         long prompts from stalling the decode batch."""
         if not self._prefilling:
             return
+        with self._phase("prefill_chunk"):
+            pf, logits = self._prefill_one_chunk()
+        if pf is None:
+            return
+        req, s = pf.req, pf.slot
+        nxt = self._first_token(req, logits)
+        finished = self._finish_at_admit(req, nxt)
+        self._emit_token(req, nxt, first=True)
+        if finished:
+            self._free_slot_pages(s, req)
+            return
+        self.pos[s] = pf.t
+        self.slots[s] = req
+
+    def _prefill_one_chunk(self):
+        """Dispatch the oldest mid-prefill request's next chunk and write
+        its pages back. Returns (the request's `_Prefilling`, the chunk's
+        logits) once its prompt is fully prefilled, else (None, None)."""
         pf = self._prefilling[0]
         off = pf.written
         toks = pf.toks[off:off + pf.chunk]
@@ -574,25 +603,19 @@ class ServingEngine:
                                "stage_v": new["stage_v"]})
         pf.written += pf.chunk
         if pf.written < pf.target:
-            return
+            return None, None
         # prompt fully prefilled: release the stage-only page surplus
-        # (stage tiles past the decode horizon) and activate the slot
-        req, s = pf.req, pf.slot
+        # (stage tiles past the decode horizon); the caller activates the
+        # slot
+        s = pf.slot
         self._prefilling.popleft()
         self._prefill_slots.discard(s)
         if len(pf.pages) > pf.gen_pages:
-            self.pool.free(req.uid, pf.pages[pf.gen_pages:])
+            self.pool.free(pf.req.uid, pf.pages[pf.gen_pages:])
         self._bt[s, :] = 0
         self._bt[s, :pf.gen_pages] = pf.pages[:pf.gen_pages]
         self._sync_tables()
-        nxt = self._first_token(req, logits)
-        finished = self._finish_at_admit(req, nxt)
-        self._emit_token(req, nxt, first=True)
-        if finished:
-            self._free_slot_pages(s, req)
-            return
-        self.pos[s] = pf.t
-        self.slots[s] = req
+        return pf, logits
 
     def _free_slot_pages(self, s: int, req: Request):
         self.pool.free(req.uid)
@@ -603,6 +626,20 @@ class ServingEngine:
     def _active(self) -> List[int]:
         return [i for i, r in enumerate(self.slots) if r is not None]
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """One host phase of a step: a `jax.profiler` span
+        `engine.<name>`, on the device trace's clock when a profiler
+        trace is on, and its host seconds added to the step's
+        `StepEvents.phases`."""
+        t = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation("engine." + name):
+                yield
+        finally:
+            self._phases[name] = (self._phases.get(name, 0.0)
+                                  + time.perf_counter() - t)
+
     def step(self) -> StepEvents:
         """One engine iteration: admit, at most one prefill chunk (paged
         mode), then one batched decode step for every active slot.
@@ -612,57 +649,81 @@ class ServingEngine:
         queue/slot occupancy. Both serve loops (`run_until_drained` and
         the asyncio front end in `serve/frontend.py`) drive this one
         method and consume the events; nothing else mutates the engine.
+
+        The step is the profiler span `engine.step` with `step_num` =
+        `StepEvents.step`; its phases are the spans `engine.admit`,
+        `engine.prefill_chunk`, `engine.first_token`, `engine.decode`,
+        `engine.token_sync`, `engine.emit` and `engine.tables`
+        (docs/serving.md).
         """
+        with jax.profiler.StepTraceAnnotation("engine.step",
+                                              step_num=self.steps_run):
+            return self._step()
+
+    def _step(self) -> StepEvents:
         t_start = time.monotonic()
         self._token_events = []
         self._admitted_uids = []
+        self._phases = {}
+        self._table_uploads = 0
         chunks_before = self.prefill_chunks_run
-        self._admit()
+        with self._phase("admit"):
+            self._admit()
         if self.paged:
             self._run_prefill_chunk()
         act = self._active()
         decode_batch = len(act)
         if act:
-            tokens = np.zeros((self.cfg.batch_slots, 1), np.int32)
-            for i in act:
-                tokens[i, 0] = self.slots[i].out_tokens[-1]
-            logits, self.caches = self._decode(
-                self.params, self.caches, jnp.asarray(tokens),
-                jnp.asarray(self.pos))
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
-            for i in act:
-                req = self.slots[i]
-                self.pos[i] += 1
-                tok = int(nxt[i])
-                req.out_tokens.append(tok)
-                if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
-                    reason = "eos"
-                elif len(req.out_tokens) >= req.max_new_tokens:
-                    reason = "max_new_tokens"
-                elif int(self.pos[i]) >= self.cfg.max_len - 1:
-                    # out of cache rows before the token budget: surface
-                    # the truncation instead of silently stopping early
-                    reason = "length_cap"
-                else:
-                    self._emit_token(req, tok, first=False)
-                    continue
-                req.done = True
-                req.finish_reason = reason
-                req.t_done = time.monotonic()
-                self.completed.append(req)
-                self.slots[i] = None
-                if self.paged:
-                    self._free_slot_pages(i, req)
-                self._emit_token(req, tok, first=False)
+            with self._phase("decode"):
+                tokens = np.zeros((self.cfg.batch_slots, 1), np.int32)
+                for i in act:
+                    tokens[i, 0] = self.slots[i].out_tokens[-1]
+                logits, self.caches = self._decode(
+                    self.params, self.caches, jnp.asarray(tokens),
+                    jnp.asarray(self.pos))
+            with self._phase("token_sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            with self._phase("emit"):
+                self._emit_decoded(act, nxt)
         ev = StepEvents(
             step=self.steps_run, t_start=t_start, t_end=time.monotonic(),
             admitted=self._admitted_uids, prefill_chunks=(
                 self.prefill_chunks_run - chunks_before),
             decode_batch=decode_batch, tokens=self._token_events,
             queue_depth=len(self.queue), active=len(self._active()),
-            prefilling=len(self._prefilling) if self.paged else 0)
+            prefilling=len(self._prefilling) if self.paged else 0,
+            phases=self._phases, table_uploads=self._table_uploads)
         self.steps_run += 1
         return ev
+
+    def _emit_decoded(self, act: List[int], nxt: np.ndarray):
+        """Append each active slot's sampled token, finish the requests
+        that stop on it (freeing their slots and pages), and record the
+        step's token events."""
+        for i in act:
+            req = self.slots[i]
+            self.pos[i] += 1
+            tok = int(nxt[i])
+            req.out_tokens.append(tok)
+            if self.cfg.eos_id >= 0 and tok == self.cfg.eos_id:
+                reason = "eos"
+            elif len(req.out_tokens) >= req.max_new_tokens:
+                reason = "max_new_tokens"
+            elif int(self.pos[i]) >= self.cfg.max_len - 1:
+                # out of cache rows before the token budget: surface
+                # the truncation instead of silently stopping early
+                reason = "length_cap"
+            else:
+                self._emit_token(req, tok, first=False)
+                continue
+            req.done = True
+            req.finish_reason = reason
+            req.t_done = time.monotonic()
+            self.completed.append(req)
+            self.slots[i] = None
+            if self.paged:
+                self._free_slot_pages(i, req)
+            self._emit_token(req, tok, first=False)
 
     def has_work(self) -> bool:
         """True while a `step()` could make progress: requests queued,
@@ -728,11 +789,8 @@ class ServingEngine:
             return {"n_devices": 1, "pool_bytes_total": 0,
                     "pool_bytes_per_device": 0,
                     "occupancy_per_device": []}
-        mesh = backends.current_mesh()
-        tp = 1
-        if mesh is not None:
-            from repro.sharding.rules import mesh_axis_sizes
-            tp = mesh_axis_sizes(mesh).get("model", 1) or 1
+        occ = self.device_pool_occupancy()
+        tp = len(occ)
         total = 0
         flat = jax.tree_util.tree_flatten_with_path(self.caches)[0]
         for kp, leaf in flat:
@@ -741,11 +799,22 @@ class ServingEngine:
             if name in ("k", "v", "k_data", "v_data", "k_scl", "v_scl",
                         "stage_k", "stage_v"):
                 total += int(leaf.size * leaf.dtype.itemsize)
-        occ = float(self.pool.stats()["occupancy"])
-        return {"n_devices": int(tp),
+        return {"n_devices": tp,
                 "pool_bytes_total": int(total),
                 "pool_bytes_per_device": int(total // tp),
-                "occupancy_per_device": [occ] * int(tp)}
+                "occupancy_per_device": occ}
+
+    def device_pool_occupancy(self) -> List[float]:
+        """The pool's occupancy once per "model"-axis shard of the
+        installed mesh (`[occupancy]` without one; paged mode): the
+        per-step gauge of `device_pool_stats`, without its walk over the
+        cache pytree."""
+        mesh = backends.current_mesh()
+        tp = 1
+        if mesh is not None:
+            from repro.sharding.rules import mesh_axis_sizes
+            tp = mesh_axis_sizes(mesh).get("model", 1) or 1
+        return [float(self.pool.occupancy())] * int(tp)
 
     def defrag(self):
         """Compact live pages onto the low end of the pool (paged mode):

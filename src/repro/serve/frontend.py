@@ -34,6 +34,9 @@ Design notes (docs/serving.md has the full architecture):
   after its last token is yielded.
 - When the engine drains, the loop parks on an event instead of
   busy-polling; `submit()` wakes it. `drain()` awaits the parked state.
+- The loop's own work between two steps (publish, intake, the work
+  check) is the profiler span `frontend.turn`; the step is
+  `engine.step` (docs/serving.md, "Profiler spans").
 """
 from __future__ import annotations
 
@@ -41,6 +44,7 @@ import asyncio
 import collections
 from typing import Deque, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.serve.engine import ServingEngine, StepEvents
@@ -191,9 +195,18 @@ class AsyncFrontend:
 
     async def _serve_loop(self) -> None:
         loop = asyncio.get_running_loop()
+        ev: Optional[StepEvents] = None
         while True:
-            self._flush_intake()
-            if not self._has_work():
+            # `frontend.turn`: the loop's work between the return of one
+            # engine step and the dispatch of the next (or the park); the
+            # executor's two hand-offs and the wait for work lie outside
+            with jax.profiler.TraceAnnotation("frontend.turn"):
+                if ev is not None:
+                    self._publish(ev)
+                    ev = None
+                self._flush_intake()
+                work = self._has_work()
+            if not work:
                 self._idle.set()
                 if self._closing:
                     return
@@ -204,7 +217,6 @@ class AsyncFrontend:
             # the blocking jitted step runs off-loop so stream consumers
             # and new submissions stay live while the device works
             ev = await loop.run_in_executor(None, self.engine.step)
-            self._publish(ev)
 
     def _publish(self, ev: StepEvents) -> None:
         """Fan one step's token events out to their streams and the

@@ -7,8 +7,10 @@ front end publishes tokens from — and accumulates two record streams:
   step records     — one per engine step: wall time, queue depth, batch
                      occupancy, decode batch size, prefill-chunk
                      interleaving, page-pool occupancy/fragmentation
-                     gauges (paged mode), and the per-step *delta* of
-                     `backends.dispatch_stats()` (so fused-vs-fallback
+                     gauges (paged mode), the host milliseconds of each
+                     engine phase (`phases_ms`) and the block-table
+                     uploads (`table_uploads`), and the per-step *delta*
+                     of `backends.dispatch_stats()` (so fused-vs-fallback
                      attribution lands on the step that traced it).
   request records  — one per completed request: TTFT, TPOT, end-to-end
                      latency, token count, finish reason, queue position.
@@ -22,7 +24,11 @@ table; all times are `time.monotonic()` seconds):
 |                    | prefill token's sample time minus submission)     |
 | `tpot_s`           | time per output token after the first:            |
 |                    | `(t_done - t_first) / (n_tokens - 1)`; absent     |
-|                    | (`None`) for single-token requests                |
+|                    | (`None`) for single-token requests. A per-request |
+|                    | mean: it hides the tail that `itl_s` shows        |
+| `itl_s`            | inter-token latency: every gap between a          |
+|                    | request's consecutive tokens, a token's time      |
+|                    | being its step's `StepEvents.t_end`               |
 | `latency_s`        | end-to-end: `t_done - t_submit`                   |
 | `queue_depth`      | requests waiting in the engine queue AFTER a step |
 | `batch_occupancy`  | decode batch size / `batch_slots` for the step    |
@@ -39,6 +45,12 @@ table; all times are `time.monotonic()` seconds):
 | `dispatch` / `fallbacks` | folded `backends.dispatch_stats()` deltas:  |
 |                    | keys per backends/base.py; `fallbacks` sums every |
 |                    | `"->fallback:"` key (quantized serving wants 0)   |
+| `phases_ms`        | host milliseconds of each engine phase in the     |
+|                    | step (`StepEvents.phases`, the `engine.<phase>`   |
+|                    | profiler spans); the summary gives one            |
+|                    | distribution per phase, over the steps that ran it|
+| `table_uploads`    | block-table uploads in the step (paged mode; one  |
+|                    | `engine.tables` span each)                        |
 
 Distributions (`_dist`) report `n/mean/p50/p95/min/max`.
 
@@ -96,6 +108,8 @@ class MetricsLedger:
         # attribute them to the step whose jit trace recorded them
         self._last_dispatch = collections.Counter(backends.dispatch_stats())
         self._dispatch_total: collections.Counter = collections.Counter()
+        self._last_token_t: Dict[int, float] = {}   # uid -> its last token
+        self._itl: List[float] = []
 
     # ---------------------------------------------------------- recording
     def _capture_meta(self, engine: ServingEngine) -> dict:
@@ -133,19 +147,25 @@ class MetricsLedger:
             "queue_depth": ev.queue_depth,
             "active": ev.active,
             "prefilling": ev.prefilling,
+            "phases_ms": {k: v * 1e3 for k, v in ev.phases.items()},
+            "table_uploads": ev.table_uploads,
         }
+        for te in ev.tokens:
+            last = self._last_token_t.pop(te.uid, None)
+            if last is not None:
+                self._itl.append(ev.t_end - last)
+            if not te.done:
+                self._last_token_t[te.uid] = ev.t_end
         if engine.paged:
             pool = engine.pool
             rec["pool_occupancy"] = pool.occupancy()
             rec["pool_used_pages"] = pool.used_pages
             rec["pool_fragmentation"] = pool.fragmentation()
             rec["pool_alloc_failures"] = pool.alloc_failures
-            if hasattr(engine, "device_pool_stats"):
-                # per-device pool-occupancy gauge: under a sharded mesh
-                # each "model"-axis shard holds 1/tp of the pool bytes
-                # at the SAME page occupancy (pages allocate globally)
-                rec["pool_device_occupancy"] = \
-                    engine.device_pool_stats()["occupancy_per_device"]
+            # per-device pool-occupancy gauge: under a sharded mesh each
+            # "model"-axis shard holds 1/tp of the pool bytes at the SAME
+            # page occupancy (pages allocate globally)
+            rec["pool_device_occupancy"] = engine.device_pool_occupancy()
         if delta:
             rec["dispatch"] = dict(delta)
         self.step_records.append(rec)
@@ -169,11 +189,13 @@ class MetricsLedger:
     def snapshot(self) -> dict:
         """Structured summary of everything recorded so far (the
         `"summary"` JSONL record): request-level TTFT/TPOT/latency
-        distributions, step-level queue/occupancy distributions, the
-        chunked-prefill interleave ratio, and the folded dispatch ledger
-        with its fallback total."""
+        distributions, the inter-token gaps, step-level queue/occupancy
+        distributions, the chunked-prefill interleave ratio, the folded
+        dispatch ledger with its fallback total, and per engine phase
+        the distribution of its host milliseconds."""
         steps = self.step_records
         reqs = self.request_records
+        phases = sorted({k for r in steps for k in r["phases_ms"]})
         chunk_steps = [r for r in steps if r["prefill_chunks"] > 0]
         interleaved = [r for r in chunk_steps if r["decode_batch"] > 0]
         fallbacks = sum(v for k, v in self._dispatch_total.items()
@@ -186,6 +208,7 @@ class MetricsLedger:
             "wall_s": steps[-1]["t_s"] if steps else 0.0,
             "ttft_s": _dist([r["ttft_s"] for r in reqs]),
             "tpot_s": _dist([r["tpot_s"] for r in reqs]),
+            "itl_s": _dist(self._itl),
             "latency_s": _dist([r["latency_s"] for r in reqs]),
             "queue_depth": _dist([r["queue_depth"] for r in steps]),
             "batch_occupancy": _dist([r["batch_occupancy"]
@@ -199,6 +222,9 @@ class MetricsLedger:
                 r["finish_reason"] for r in reqs)),
             "dispatch": dict(self._dispatch_total),
             "fallbacks": fallbacks,
+            "phases_ms": {k: _dist([r["phases_ms"].get(k) for r in steps])
+                          for k in phases},
+            "table_uploads": sum(r["table_uploads"] for r in steps),
         }
         if steps and "pool_occupancy" in steps[0]:
             snap["pool_occupancy"] = _dist(

@@ -16,6 +16,7 @@ chip cannot be read back without one).
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,10 +56,14 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-def _compile(fn, sharding, *shapes):
+def _compile(fn, sharding, *shapes, kernel: str):
+    """Compile `fn` for the described chip; its kernel shows in the HLO,
+    and so in the device trace, under the `pallas_call`'s `name`."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{kernel}(\.\d+)? = .*tpu_custom_call", text), kernel
     return compiled
 
 
@@ -89,7 +94,7 @@ def test_fused_matmul_compiles(one_chip, a_mode, lhs, k, n):
         return ops.fused_ovp_matmul(x, w, out_dtype=jnp.bfloat16)
 
     _compile(fn, one_chip, (lhs, jnp.bfloat16), ((k // 2, n), jnp.uint8),
-             ((1, n), jnp.float32))
+             ((1, n), jnp.float32), kernel="_fused_padded")
 
 
 def test_static_prologue_compiles(one_chip):
@@ -100,7 +105,8 @@ def test_static_prologue_compiles(one_chip):
 
     _compile(fn, one_chip, ((1, CHUNK, D_MODEL), jnp.bfloat16),
              ((D_MODEL // 2, D_MODEL), jnp.uint8),
-             ((1, D_MODEL), jnp.float32), ((), jnp.float32))
+             ((1, D_MODEL), jnp.float32), ((), jnp.float32),
+             kernel="_fused_padded")
 
 
 def test_grouped_matmul_compiles(one_chip):
@@ -114,7 +120,17 @@ def test_grouped_matmul_compiles(one_chip):
         return ops.grouped_ovp_matmul(x, w, out_dtype=jnp.bfloat16)
 
     _compile(fn, one_chip, ((1, e, c, d), jnp.bfloat16),
-             ((e, d // 2, f), jnp.uint8), ((e, 1, f), jnp.float32))
+             ((e, d // 2, f), jnp.uint8), ((e, 1, f), jnp.float32),
+             kernel="_grouped_padded")
+
+
+def test_ovp_encode_compiles(one_chip):
+    """The standalone encoder on one prefill chunk of activations."""
+    def fn(x, scale):
+        return ops.ovp_encode(x, scale)
+
+    _compile(fn, one_chip, ((CHUNK, D_MODEL), jnp.bfloat16),
+             ((), jnp.float32), kernel="ovp_encode")
 
 
 def _pools():
@@ -132,7 +148,7 @@ def test_paged_packed_decode_attention_compiles(one_chip, precision):
     with jax.default_matmul_precision(precision):
         _compile(fn, one_chip, ((SLOTS, 1, HKV, HEAD_DIM), jnp.bfloat16),
                  *_pools(), ((SLOTS, PAGES_PER_ROW), jnp.int32),
-                 ((SLOTS,), jnp.int32))
+                 ((SLOTS,), jnp.int32), kernel="_decode_attn_call")
 
 
 @pytest.mark.parametrize("precision", ["default", "highest"])
@@ -147,4 +163,4 @@ def test_packed_fused_prefill_compiles(one_chip, precision):
     with jax.default_matmul_precision(precision):
         _compile(fn, one_chip, ((1, CHUNK, HKV, HEAD_DIM), jnp.bfloat16),
                  *_pools(), ((1, PAGES_PER_ROW), jnp.int32), stage, stage,
-                 ((1, CHUNK), jnp.int32))
+                 ((1, CHUNK), jnp.int32), kernel="_prefill_call")
