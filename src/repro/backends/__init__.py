@@ -126,11 +126,10 @@ def _record(backend_name: str, reason: Optional[str],
     _DISPATCH_STATS[dispatch_key(backend_name, reason, marker)] += 1
 
 
-def count_pallas_calls(fn, *args) -> int:
-    """Number of pallas_call primitives in fn's jaxpr (recursing through
-    pjit/closed-call sub-jaxprs) — the kernel-dispatch count of a pipeline.
-    Benchmarks and tests use it to verify a backend's fusion claim
-    (`dispatches_per_matmul`) against the traced program."""
+def pallas_call_eqns(fn, *args) -> list:
+    """The pallas_call equations of fn's jaxpr, in program order
+    (recursing through pjit/closed-call sub-jaxprs); each carries its
+    `grid_mapping` in `params`."""
     closed = jax.make_jaxpr(fn)(*args)
 
     def sub_jaxprs(v):
@@ -146,17 +145,23 @@ def count_pallas_calls(fn, *args) -> int:
             elif hasattr(v, "eqns"):
                 yield v
 
-    def walk(jaxpr) -> int:
-        n = 0
+    def walk(jaxpr):
         for eqn in jaxpr.eqns:
             if eqn.primitive.name == "pallas_call":
-                n += 1
+                yield eqn
             for v in eqn.params.values():
                 for inner in sub_jaxprs(v):
-                    n += walk(inner)
-        return n
+                    yield from walk(inner)
 
-    return walk(closed.jaxpr)
+    return list(walk(closed.jaxpr))
+
+
+def count_pallas_calls(fn, *args) -> int:
+    """Number of pallas_call primitives in fn's jaxpr — the
+    kernel-dispatch count of a pipeline. Benchmarks and tests use it to
+    verify a backend's fusion claim (`dispatches_per_matmul`) against the
+    traced program."""
+    return len(pallas_call_eqns(fn, *args))
 
 
 def dispatch(x: jax.Array, w, policy: QuantPolicy,
@@ -295,7 +300,7 @@ __all__ = ["QuantizedMatmulBackend", "register", "get_backend", "available",
            "dispatch_stats",
            "reset_dispatch_stats",
            "act_scale_stats", "reset_act_scale_stats",
-           "count_pallas_calls", "quantize_activation",
+           "count_pallas_calls", "pallas_call_eqns", "quantize_activation",
            "resolve_act_scale", "act_normal_dtype", "XlaBackend",
            "PallasBackend", "PallasInterpretBackend", "ReferenceBackend",
            "ShardedPallasBackend", "ShardedPallasInterpretBackend",

@@ -3,9 +3,10 @@
 Activation OVP quantization runs as the kernel prologue at a precomputed
 per-tensor/per-row scale (no packed activation tensor in HBM, no XLA
 encode -> kernel decode round trip), weight codes decode in VMEM, and both
-scales apply in the accumulator epilogue. 2-D and 3-D lhs share the kernel
-via its batch grid dim, so serving decode-step GEMMs hit the fused path
-without reshape glue.
+scales apply in the accumulator epilogue. 2-D and 3-D lhs share the kernel:
+leading lhs dims fold into M, so a serving decode step's `(slots, 1, K)`
+rows form one M tile and each weight tile is fetched and decoded once per
+call, not once per slot.
 
 Stacked per-expert weights `(E, K, N)` run the grouped kernel: an expert
 grid dim indexes the weight stack, the lhs carries a matching `(…, E, C,

@@ -1,7 +1,8 @@
 """Jit'd public wrappers around the fused OVP Pallas kernel.
 
-Handles: lhs normalization to the kernel's 3-D (batch, M, K) layout, shape
-padding to block multiples, scale broadcasting into the kernel's epilogue
+Handles: lhs normalization to the kernel's 3-D (batch, M, K) layout (every
+lhs row in M, batch 1, for the shared 2-D weight), shape padding to block
+multiples, scale broadcasting into the kernel's epilogue
 layout (per-row activation / per-output-channel weight), QuantizedTensor
 plumbing, and the interpret switch (CPU validation vs TPU execution).
 
@@ -73,15 +74,18 @@ def _fused_padded(a3: jax.Array, sa3: jax.Array,
     return out[:, :m, :n].astype(out_dtype)
 
 
-def _as_3d(x: jax.Array):
-    """(…, M, K) -> ((B, M, K), lead) with all leading dims folded into B."""
+def _as_rows(x: jax.Array):
+    """(…, M, K) -> ((1, R, K), rows) with every leading dim folded into M:
+    R = prod(rows), rows = x.shape[:-1].
+
+    The 2-D kernel's weight is one shared (Kw, N) that the batch grid dim
+    does not index, so rows spread over that dim would each fetch and
+    decode every weight tile again. Folded into M, a decode step's
+    (slots, 1, K) rows form one M tile: each weight tile is fetched and
+    decoded once per call."""
     if x.ndim < 2:
         raise ValueError(f"lhs must be at least 2-D, got {x.shape}")
-    lead = x.shape[:-2]
-    b = 1
-    for d in lead:
-        b *= d
-    return x.reshape(b, x.shape[-2], x.shape[-1]), lead
+    return x.reshape(1, -1, x.shape[-1]), x.shape[:-1]
 
 
 def _as_4d(x: jax.Array):
@@ -96,12 +100,12 @@ def _as_4d(x: jax.Array):
 
 
 def _row_scale(s, x: jax.Array) -> jax.Array:
-    """Broadcast a scalar / per-row scale to the kernel's (B, M, 1)."""
+    """Broadcast a scalar / per-row scale to the kernel's (1, R, 1)."""
     s = jnp.asarray(s, jnp.float32)
     target = x.shape[:-1] + (1,)
     if s.ndim and s.shape == x.shape[:-1]:
         s = s[..., None]
-    s3, _ = _as_3d(jnp.broadcast_to(s, target))
+    s3, _ = _as_rows(jnp.broadcast_to(s, target))
     return s3
 
 
@@ -126,7 +130,8 @@ def fused_ovp_matmul(x: Union[jax.Array, QuantizedTensor],
     per-tensor or per-row `act_scale`; no packed activation tensor is ever
     materialized) — or a pre-quantized `QuantizedTensor` whose codes are
     decoded in the prologue. Weight pairs must run along K; any leading lhs
-    dims are batch (3-D decode-step GEMMs take the same path as 2-D).
+    dims fold into M (`_as_rows`), so a 3-D decode-step GEMM is one
+    (1, slots, K) call whose weight tiles are fetched once, as for 2-D.
 
     `static_act_scale` (the calibrated per-site scalar — a Python float
     or 0-d array) replaces `act_scale`: it reaches the kernel as a single
@@ -139,13 +144,13 @@ def fused_ovp_matmul(x: Union[jax.Array, QuantizedTensor],
     static = False
     if isinstance(x, QuantizedTensor):
         a_mode = "codes4" if x.is_packed else "codes8"
-        a3, lead = _as_3d(x.data)
+        a3, rows = _as_rows(x.data)
         sa3 = _row_scale(x.scale, x.data)
         a_dtype = x.normal_dtype
     elif a_dtype is not None:
         if static_act_scale is not None:
             a_mode = "quantize"
-            a3, lead = _as_3d(x)
+            a3, rows = _as_rows(x)
             sa3 = jnp.asarray(static_act_scale,
                               jnp.float32).reshape(1, 1)
             static = True
@@ -155,19 +160,18 @@ def fused_ovp_matmul(x: Union[jax.Array, QuantizedTensor],
                              "static_act_scale constant")
         else:
             a_mode = "quantize"
-            a3, lead = _as_3d(x)
+            a3, rows = _as_rows(x)
             sa3 = _row_scale(act_scale, x)
     else:
         a_mode = "fp"
-        a3, lead = _as_3d(x)
+        a3, rows = _as_rows(x)
         sa3 = jnp.ones((a3.shape[0], a3.shape[1], 1), jnp.float32)
         a_dtype = w.normal_dtype
     out = _fused_padded(a3, sa3, w.data, sw, w_dtype=w.normal_dtype,
                         a_mode=a_mode, a_dtype=a_dtype, a_static=static,
                         out_dtype=out_dtype, interpret=interpret,
                         bm=bm, bn=bn, bk=bk)
-    return out.reshape(*lead, out.shape[-2], out.shape[-1]) if lead \
-        else out[0]
+    return out.reshape(*rows, n)
 
 
 # --------------------------------------------------------------------------
@@ -334,8 +338,8 @@ def ovp_matmul(a: Union[jax.Array, QuantizedTensor], w: QuantizedTensor,
                out_dtype=jnp.float32, interpret: bool = False) -> jax.Array:
     """Public entry: dispatch from operand types (4-bit packed or int8 OVP).
 
-    Leading batch dims of `a` ride the kernel's batch grid dim. Weight
-    pairs must run along K (pair_axis == 0 of the 2-D weight).
+    Leading batch dims of `a` fold into the kernel's M. Weight pairs must
+    run along K (pair_axis == 0 of the 2-D weight).
     """
     return fused_ovp_matmul(a, w, out_dtype=out_dtype, interpret=interpret)
 

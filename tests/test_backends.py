@@ -21,6 +21,7 @@ from repro.core.ovp import QuantizedTensor, ovp_dequantize, ovp_quantize
 from repro.core.policy import QuantPolicy
 from repro.core.qlinear import qmatmul, quantize_weight
 from repro.core.quantizer import sigma_init_scale
+from repro.kernels import ops
 
 from test_ovp import heavy_tailed
 
@@ -59,6 +60,38 @@ def operands():
     x3 = heavy_tailed(ka, (3, 16, k), outlier_frac=0.01, outlier_scale=9.0)
     w = heavy_tailed(kw, (k, n), outlier_frac=0.01, outlier_scale=9.0)
     return x2, x3, w
+
+
+# lhs of a decode step (slots, 1, K) and of a few rows per slot, in every
+# activation mode the fused 2-D kernel takes
+DECODE_LHS = [(4, 1, 64), (32, 1, 64), (4, 3, 64)]
+DECODE_MODES = ["quantize_tensor", "quantize_row", "codes4", "fp"]
+
+
+def _decode_matmul(mode, x, w):
+    """(fused fn of x, reference result) of one decode-step matmul with
+    activations in `mode`."""
+    kind = "w4a16" if mode == "fp" else "w4a4"
+    pol = make_policy(kind, "channel", "pallas_interpret")
+    wq = quantize_weight(w, pol)
+    ref = dataclasses.replace(pol, backend="reference")
+    if mode == "codes4":
+        scale = sigma_init_scale(x, "int4")
+
+        def fn(x):
+            return ops.ovp_matmul(ovp_quantize(x, scale, "int4", pair_axis=-1),
+                                  wq, interpret=True)
+
+        aq = ovp_quantize(x, scale, "int4", pair_axis=-1)
+        return fn, ovp_dequantize(aq) @ ovp_dequantize(wq)
+    if mode == "quantize_row":
+        pol = dataclasses.replace(pol, act_scale_mode="static")
+        ref = dataclasses.replace(ref, act_scale_mode="static")
+        rows = jnp.linspace(0.05, 0.4, int(np.prod(x.shape[:-1])))
+        scale = rows.reshape(x.shape[:-1] + (1,))
+        return (lambda x: qmatmul(x, wq, pol, act_scale=scale),
+                qmatmul(x, wq, ref, act_scale=scale))
+    return lambda x: qmatmul(x, wq, pol), qmatmul(x, wq, ref)
 
 
 class TestRegistry:
@@ -140,18 +173,26 @@ class TestBackendEquivalence:
                        act_scale=row_scale)
         assert rel_err(got, want) < 1e-5
 
-    def test_decode_single_row_3d(self):
-        """(B, 1, K) decode-step GEMM on the fused kernel batch dim."""
+    @pytest.mark.parametrize("mode", DECODE_MODES)
+    @pytest.mark.parametrize("lhs", DECODE_LHS,
+                             ids=["x".join(map(str, s)) for s in DECODE_LHS])
+    def test_decode_single_row_3d(self, lhs, mode):
+        """(…, M, K) decode-step GEMMs fold every leading dim into M: one
+        pallas_call whose grid has batch 1 and whose M blocks cover all
+        rows, so each weight tile is fetched once per call, not per slot."""
         key = jax.random.PRNGKey(3)
-        x = jax.random.normal(key, (4, 1, 64))
+        x = jax.random.normal(key, lhs)
         w = jax.random.normal(jax.random.split(key)[0], (64, 32))
-        pol = make_policy("w4a4", "channel", "pallas_interpret")
-        wq = quantize_weight(w, pol)
-        got = qmatmul(x, wq, pol)
-        want = qmatmul(x, wq,
-                       dataclasses.replace(pol, backend="reference"))
-        assert got.shape == (4, 1, 32)
+        fn, want = _decode_matmul(mode, x, w)
+        got = fn(x)
+        assert got.shape == lhs[:-1] + (32,)
         assert rel_err(got, want) < 1e-5
+        [eqn] = backends.pallas_call_eqns(fn, x)
+        gm = eqn.params["grid_mapping"]
+        [out] = gm.block_mappings_output
+        bm = out.block_shape[1].block_size
+        assert gm.grid[0] == 1
+        assert gm.grid[1] * bm >= int(np.prod(lhs[:-1]))
 
 
 class TestMixedPrecision:
@@ -174,7 +215,6 @@ class TestMixedPrecision:
     def test_prepacked_int8_activation_tensor(self, operands):
         """ops.ovp_matmul no longer raises NotImplementedError on int8
         OVP operands (one code per byte)."""
-        from repro.kernels import ops
         x2, _, w = operands
         wq = ovp_quantize(w, sigma_init_scale(w, "int8"), "int8",
                           pair_axis=0)
@@ -216,7 +256,6 @@ class TestFusedSingleDispatch:
         """Acceptance: fused W4A4 with in-kernel activation quantization
         and in-epilogue scales is a single pallas_call."""
         from repro.backends import count_pallas_calls
-        from repro.kernels import ops
         x2, _, w = operands
         pol = make_policy("w4a4", "channel", "pallas_interpret")
         wq = quantize_weight(w, pol)

@@ -211,12 +211,7 @@ class TestStaticDynamicEquivalence:
                                         static_act_scale=0.1,
                                         interpret=True)
 
-        assert backends.count_pallas_calls(static_mm, x) == 1
-        jaxpr = jax.make_jaxpr(static_mm)(x)
-        [eqn] = [e for e in jax.tree_util.tree_leaves(
-            [list(j.eqns) for j in _all_jaxprs(jaxpr.jaxpr)],
-            is_leaf=lambda v: hasattr(v, "primitive"))
-            if e.primitive.name == "pallas_call"]
+        [eqn] = backends.pallas_call_eqns(static_mm, x)
         shapes = [tuple(v.aval.shape) for v in eqn.invars]
         assert (1, 1) in shapes          # the scalar scale word
         assert (x.shape[0], x.shape[1], 1) not in shapes \
@@ -229,16 +224,6 @@ class TestStaticDynamicEquivalence:
         ops.fused_ovp_matmul(x, wq, a_dtype="int4",
                              static_act_scale=0.25, interpret=True)
         assert ops._fused_padded._cache_size() == n_traces
-
-
-def _all_jaxprs(jaxpr):
-    yield jaxpr
-    for eqn in jaxpr.eqns:
-        for v in eqn.params.values():
-            for item in (v if isinstance(v, (tuple, list)) else [v]):
-                inner = getattr(item, "jaxpr", None)
-                if inner is not None and hasattr(inner, "eqns"):
-                    yield from _all_jaxprs(inner)
 
 
 class TestValidation:

@@ -72,11 +72,19 @@ def _w4(wd, ws, k):
                            pair_axis=0, orig_dim=k)
 
 
-# (lhs shape, K, N): decode rows (8 slots x 1 token) and one prefill chunk
+# the benchmark cells' decode step: 32 slots x 1 token, which the wrapper
+# folds into one full-dimension bm=32 M block; qwen2-7b d 3584, d_ff 18944
+BENCH_SLOTS, D_MODEL_7B, D_FF_7B = 32, 3584, 18944
+
+# (lhs shape, K, N): decode rows (8 slots x 1 token) and one prefill chunk,
+# then the cells' 32-slot decode rows at 0.5b and 7b widths
 _MATMULS = [((SLOTS, 1, D_MODEL), D_MODEL, D_FF),
             ((1, CHUNK, D_MODEL), D_MODEL, D_FF),
             ((SLOTS, 1, D_FF), D_FF, D_MODEL),
-            ((1, CHUNK, D_FF), D_FF, D_MODEL)]
+            ((1, CHUNK, D_FF), D_FF, D_MODEL),
+            ((BENCH_SLOTS, 1, D_MODEL), D_MODEL, D_FF),
+            ((BENCH_SLOTS, 1, D_FF), D_FF, D_MODEL),
+            ((BENCH_SLOTS, 1, D_MODEL_7B), D_MODEL_7B, D_FF_7B)]
 
 
 @pytest.mark.parametrize("a_mode", ["w4a4_dynamic", "w4a16"])
