@@ -1,9 +1,13 @@
-"""Plain reference of the Qwen2 architecture (Qwen1.5 and Qwen2 share it),
-in float32 `jax.numpy` with no kernel, cache, batching or quantization.
+"""The Qwen2 architecture as the benchmark knows it (Qwen1.5 and Qwen2
+share it): its seeded weights, the served program's layout and sizes of
+them, the fused-matmul calls and operations of one forward, and the plain
+float32 reference in `jax.numpy` with no kernel, cache, batching or
+quantization.
 
-It reads the benchmark's own weights (`perfbench/lib/weights.py`) and
-imports nothing of the served program. Equations, per the published
-Qwen2 description (arXiv:2407.10671):
+A configuration file names this module under `reference`; the harness in
+`perfbench/lib` calls these six functions and knows no leaf or
+projection by name. Nothing here imports the served program. Equations of
+the reference, per the published Qwen2 description (arXiv:2407.10671):
 
     x_0 = E[tokens]
     h   = rms(x) * g1;  q, k, v = h Wq + bq, h Wk + bk, h Wv + bv
@@ -16,6 +20,108 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+
+def make_weights(cfg, key: jax.Array) -> dict:
+    """float32 master weights drawn from `key` (`perfbench.lib.weights.
+    seed_key`), in a plain per-layer layout: every layer's leaf stacked
+    on a leading axis of `num_hidden_layers`."""
+    d, f, n = cfg.hidden_size, cfg.intermediate_size, cfg.num_hidden_layers
+    hq = cfg.num_attention_heads * cfg.head_dim
+    hkv = cfg.num_key_value_heads * cfg.head_dim
+    shapes = {
+        "wq": (n, d, hq), "wk": (n, d, hkv), "wv": (n, d, hkv),
+        "wo": (n, hq, d), "wg": (n, d, f), "wu": (n, d, f),
+        "wd": (n, f, d),
+    }
+    names = sorted(shapes) + ["bq", "bk", "bv", "ln1", "ln2", "final_norm",
+                              "embed", "lm_head"]
+    keys = dict(zip(names, jax.random.split(key, len(names))))
+
+    def normal(name, shape, std):
+        return jax.random.normal(keys[name], shape, jnp.float32) * std
+
+    w = {k: normal(k, s, s[1] ** -0.5) for k, s in shapes.items()}
+    w["bq"] = normal("bq", (n, hq), 0.02)
+    w["bk"] = normal("bk", (n, hkv), 0.02)
+    w["bv"] = normal("bv", (n, hkv), 0.02)
+    w["ln1"] = 1.0 + normal("ln1", (n, d), 0.05)
+    w["ln2"] = 1.0 + normal("ln2", (n, d), 0.05)
+    w["final_norm"] = 1.0 + normal("final_norm", (d,), 0.05)
+    # the embedding at the head's scale: a tied head then gives logits of
+    # unit scale, and no layer's input is dominated by its current token
+    w["embed"] = normal("embed", (cfg.vocab_size, d), d ** -0.5)
+    if not cfg.tie_word_embeddings:
+        w["lm_head"] = normal("lm_head", (d, cfg.vocab_size), d ** -0.5)
+    return w
+
+
+def to_program_tree(cfg, w: dict) -> dict:
+    """The same model in the served program's parameter layout
+    (`repro.models.model.Model`): one scan group per layer (`blocks/0/...`
+    stacked on the layer axis), the vocabulary padded with zero rows to
+    the program's padded width (the program masks those logits).
+
+    The program multiplies the embedding rows it reads by
+    sqrt(hidden_size), which Qwen2 does not, so its table holds the
+    embedding divided by sqrt(hidden_size): the layers then see the
+    published model's inputs. A tied head reads that same table, so its
+    logits come out divided by sqrt(hidden_size), which leaves every
+    greedy token as it is."""
+    pad = cfg.padded_vocab - cfg.vocab_size
+    table = jnp.pad(w["embed"], ((0, pad), (0, 0))) \
+        * jnp.float32(cfg.hidden_size ** -0.5)
+    if cfg.tie_word_embeddings:
+        # the program reads the embedding table as its head when tied and
+        # never this leaf, so it gets no memory
+        head = jnp.zeros((1, 1), jnp.float32)
+    else:
+        head = jnp.pad(w["lm_head"], ((0, 0), (0, pad)))
+    block = {
+        "ln1": {"gamma_scale": w["ln1"]},
+        "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk",
+                                   "bv")},
+        "ln2": {"gamma_scale": w["ln2"]},
+        "mlp": {k: w[k] for k in ("wg", "wu", "wd")},
+    }
+    return {"embed": {"table": table},
+            "final_norm": {"gamma_scale": w["final_norm"]},
+            "lm_head": {"w_out": head},
+            "blocks": {"0": block},
+            "tail": []}
+
+
+def program_sizes(cfg) -> dict:
+    """The fields of the program's `ArchConfig` (named by the file's
+    `program.arch`) that this configuration sets."""
+    return dict(n_layers=cfg.num_hidden_layers, d_model=cfg.hidden_size,
+                n_heads=cfg.num_attention_heads,
+                n_kv_heads=cfg.num_key_value_heads,
+                d_ff=cfg.intermediate_size, vocab=cfg.vocab_size,
+                head_dim=cfg.head_dim, rope_theta=cfg.rope_theta,
+                norm_eps=cfg.rms_norm_eps,
+                tie_embeddings=cfg.tie_word_embeddings)
+
+
+def layer_matmuls(cfg) -> list[tuple[str, int, int]]:
+    """(site, K, N) of the fused OVP matmul calls of one layer: the seven
+    quantized projections."""
+    d, f = cfg.hidden_size, cfg.intermediate_size
+    hq = cfg.num_attention_heads * cfg.head_dim
+    hkv = cfg.num_key_value_heads * cfg.head_dim
+    return [("wq", d, hq), ("wk", d, hkv), ("wv", d, hkv), ("wo", hq, d),
+            ("wg", d, f), ("wu", d, f), ("wd", f, d)]
+
+
+def flops_per_token(cfg, context: float) -> float:
+    """Model operations to generate one token at `context` positions of
+    history: 2 per weight of every projection and of the LM head, plus
+    the attention scores and the weighted sum over the context."""
+    weights = sum(k * n for _, k, n in layer_matmuls(cfg)) \
+        * cfg.num_hidden_layers + cfg.hidden_size * cfg.vocab_size
+    attn = 4.0 * context * cfg.num_attention_heads * cfg.head_dim \
+        * cfg.num_hidden_layers
+    return 2.0 * weights + attn
 
 
 def _rms(x, g, eps):

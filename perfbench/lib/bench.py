@@ -17,6 +17,7 @@ import dataclasses
 import gc
 import importlib
 import itertools
+import math
 import sys
 import time
 
@@ -24,6 +25,7 @@ import numpy as np
 
 from . import check, traffic as traffic_mod, weights
 from .cell import ROOT, Cell
+from .model_config import architecture
 
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".perfbench_trace"     # the newest trace of each cell
@@ -77,16 +79,26 @@ def devices(chips: int, require_tpu: bool):
 
 
 def arch_config(cfg):
-    """The program's architecture config with every size from the
-    benchmark's configuration file."""
+    """The program's architecture config with every size the
+    configuration's architecture module sets from its file."""
     from repro.configs import get_config
-    return dataclasses.replace(
-        get_config(cfg.arch), n_layers=cfg.num_hidden_layers,
-        d_model=cfg.hidden_size, n_heads=cfg.num_attention_heads,
-        n_kv_heads=cfg.num_key_value_heads, d_ff=cfg.intermediate_size,
-        vocab=cfg.vocab_size, head_dim=cfg.head_dim,
-        rope_theta=cfg.rope_theta, norm_eps=cfg.rms_norm_eps,
-        tie_embeddings=cfg.tie_word_embeddings)
+    return dataclasses.replace(get_config(cfg.arch),
+                               **architecture(cfg).program_sizes(cfg))
+
+
+def mesh_plan(cfg, chips: int):
+    """The program's device mesh for a cell on `chips` chips: none on one
+    chip; otherwise the file's `program.mesh` (data, model) sizes, which
+    cover exactly the cell's chips."""
+    if chips == 1:
+        return None
+    if math.prod(cfg.mesh) != chips or len(cfg.mesh) != 2:
+        raise ValueError(f"{cfg.name}: a cell on {chips} chips needs a "
+                         f"program.mesh of two sizes whose product is "
+                         f"{chips}, not {list(cfg.mesh)}")
+    from repro.runtime.elastic import MeshPlan
+    return MeshPlan(shape=cfg.mesh, axis_names=("data", "model"),
+                    dropped_devices=0)
 
 
 @dataclasses.dataclass
@@ -248,6 +260,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
         raise ValueError(f"traffic {spec['name']!r}: only a closed loop "
                          f"is driven, not {spec['loop']!r}")
     arch = arch_config(cfg)
+    mesh = mesh_plan(cfg, cell.chips)
     policy = serve.build_policy(arch, cfg.quant, backend=backend or cfg.backend)
     walls = {"imports": time.monotonic() - t_process}
 
@@ -264,7 +277,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
                             max_len=int(spec["max_len"]),
                             page_size=int(spec["page_size"]),
                             prefill_chunk=int(spec["prefill_chunk"]),
-                            backend=backend or cfg.backend)
+                            mesh=mesh, backend=backend or cfg.backend)
     del params
     if fault is not None:
         fault(eng)
@@ -316,6 +329,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_process: float,
     # free the program's state before the reference runs: its jitted
     # steps hold the engine, and through it the weights and caches
     del eng, rec, step, make
+    if mesh is not None:
+        backends.configure_mesh(None)
     jax.clear_caches()
     gc.collect()
     in_use = max(d.memory_stats().get("bytes_in_use", 0) for d in devs) \

@@ -3,7 +3,7 @@
 After the window has closed and the program's state is freed, a sample
 of the requests finished in the window, drawn from the seed and holding
 the longest one, is run once through the configuration's plain float32
-reference (`perfbench/configs/<reference>.py`) over prompt + served
+reference (`logits` of its architecture module) over prompt + served
 tokens. Each served token is read at the position before it in two ways:
 its gap, how far its reference logit lies below the reference's best
 logit, and its rank, how many tokens the reference puts above it (both 0
@@ -19,15 +19,10 @@ readings each limit was set from. The gaps are reported beside it.
 """
 from __future__ import annotations
 
-import importlib
-
 import numpy as np
 
 from . import weights
-
-
-def reference(cfg):
-    return importlib.import_module(f"perfbench.configs.{cfg.reference}")
+from .model_config import architecture
 
 
 def read_fn(cfg, mm=None):
@@ -37,7 +32,7 @@ def read_fn(cfg, mm=None):
     reference puts first (the control)."""
     import jax
     import jax.numpy as jnp
-    ref = reference(cfg)
+    ref = architecture(cfg)
 
     def fn(w, toks):
         # a token outside the vocabulary reads as the reference's last
@@ -78,7 +73,7 @@ def served_readings(cfg, seed: int, finished: list, k: int, pad_to: int,
     at the same positions."""
     import jax
     import jax.numpy as jnp
-    w = jax.jit(lambda key: weights.make_weights(cfg, key))(
+    w = jax.jit(lambda key: architecture(cfg).make_weights(cfg, key))(
         weights.seed_key(seed))
     fn = read_fn(cfg, mm)
     gaps, ranks = [], []

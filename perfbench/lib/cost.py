@@ -2,20 +2,17 @@
 
 These are the algorithm's numbers, independent of how the program
 implements them: a kernel that reads its weights twice still counts them
-once here, so a share of the roofline shows the waste.
+once here, so a share of the roofline shows the waste. What one layer
+holds comes from the configuration's architecture module.
 """
 from __future__ import annotations
 
-from .model_config import ModelConfig
+from .model_config import ModelConfig, architecture
 
 
 def layer_matmuls(cfg: ModelConfig) -> list[tuple[str, int, int]]:
-    """(site, K, N) of the quantized projections of one layer."""
-    d, f = cfg.hidden_size, cfg.intermediate_size
-    hq = cfg.num_attention_heads * cfg.head_dim
-    hkv = cfg.num_key_value_heads * cfg.head_dim
-    return [("wq", d, hq), ("wk", d, hkv), ("wv", d, hkv), ("wo", hq, d),
-            ("wg", d, f), ("wu", d, f), ("wd", f, d)]
+    """(site, K, N) of the fused OVP matmul calls of one layer."""
+    return architecture(cfg).layer_matmuls(cfg)
 
 
 def ovp_matmul(m: int, k: int, n: int, act_bytes: int = 2,
@@ -43,10 +40,5 @@ def least_time_s(ops: float, nbytes: float, ops_per_s: float,
 
 def flops_per_token(cfg: ModelConfig, context: float) -> float:
     """Model operations to generate one token at `context` positions of
-    history: 2 per weight of every projection and of the LM head, plus
-    the attention scores and the weighted sum over the context."""
-    weights = sum(k * n for _, k, n in layer_matmuls(cfg)) \
-        * cfg.num_hidden_layers + cfg.hidden_size * cfg.vocab_size
-    attn = 4.0 * context * cfg.num_attention_heads * cfg.head_dim \
-        * cfg.num_hidden_layers
-    return 2.0 * weights + attn
+    history."""
+    return architecture(cfg).flops_per_token(cfg, context)
